@@ -89,6 +89,8 @@ def main(argv=None) -> int:
                          "(default: cwd; 'none' disables)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     benches = get_benches()
     if args.only:
         only = args.only.split(",")

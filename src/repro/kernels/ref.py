@@ -1,6 +1,9 @@
 """Pure-jnp oracles for every Pallas kernel. These are the correctness
 reference (tests assert_allclose kernel-vs-ref across shape/dtype sweeps) and
-the portable fallback used on non-TPU backends.
+what ``kernels.ops`` runs in mode "off": the default for every kernel on
+non-TPU backends, and for the model-zoo kernels (attention, LoRA, scan) on
+a TPU too.  On a TPU the compiled aggregation kernels run unless "off" is
+asked for explicitly.
 """
 from __future__ import annotations
 

@@ -176,7 +176,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
         B, S = sh["global_batch"], sh["seq_len"]
         q_chunk = 2048 if S > 4096 else 4096
 
-        with dist.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             if sh["kind"] == "fft_round":
                 K, b = sh["clients"], sh["client_batch"]
                 step = make_fft_round_step(cfg, lr=LR, q_chunk=q_chunk)
@@ -240,9 +240,6 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
 
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        # jax 0.4.x returns a one-dict list per computation; >=0.5 a dict
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
         coll = rl.collective_bytes(hlo)
         flops = float(cost.get("flops", 0.0))

@@ -232,7 +232,7 @@ def _gqa_decode_core_seq_sharded(ctx, cfg: ModelConfig, q, k_new, v_new,
 
     cache_spec = P(bspec, m_ax, None, None)
     rep4 = P(bspec, None, None, None)
-    o, ck, cv = dist.shard_map(
+    o, ck, cv = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(rep4, rep4, rep4, cache_spec, cache_spec, P()),
         out_specs=(rep4, cache_spec, cache_spec),

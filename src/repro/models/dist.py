@@ -9,7 +9,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import inspect
 from typing import Optional, Tuple
 
 import jax
@@ -31,31 +30,6 @@ class MeshContext:
         for a in self.batch_axes:
             n *= self.mesh.shape[a]
         return n
-
-
-# jax.shard_map landed after 0.4.x (jax.experimental.shard_map before), and
-# its replication-check kwarg was renamed check_rep -> check_vma separately,
-# so detect the kwarg from the signature rather than from which import won.
-try:
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-try:
-    _CHECK_KW = ("check_vma" if "check_vma" in
-                 inspect.signature(_shard_map).parameters else "check_rep")
-except (TypeError, ValueError):
-    _CHECK_KW = "check_vma"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check_vma})
-
-
-# jax.set_mesh landed after 0.4.x; jax.sharding.use_mesh briefly preceded
-# it, and on 0.4.x the Mesh object itself is the activating context manager.
-set_mesh = getattr(jax, "set_mesh",
-                   getattr(jax.sharding, "use_mesh", lambda m: m))
 
 
 _CTX: Optional[MeshContext] = None

@@ -187,7 +187,7 @@ def moe_forward(p, cfg: ModelConfig, x) -> Tuple[jax.Array, jax.Array]:
                            cfg.num_experts_per_tok * E_loc / E) + 64)
         body = functools.partial(_moe_sharded_body, cfg=cfg, ctx=ctx,
                                  capacity=capacity)
-        out, aux = dist.shard_map(
+        out, aux = jax.shard_map(
             body, mesh=ctx.mesh,
             in_specs=(bspec, P(None, None), P(m, None, None),
                       P(m, None, None), P(m, None, None)),
@@ -211,7 +211,7 @@ def moe_forward(p, cfg: ModelConfig, x) -> Tuple[jax.Array, jax.Array]:
             .reshape(E * within, f_loc, cfg.d_model)
         body = functools.partial(_moe_sharded_body_virtual, cfg=cfg, ctx=ctx,
                                  within=within, capacity=capacity)
-        out, aux = dist.shard_map(
+        out, aux = jax.shard_map(
             body, mesh=ctx.mesh,
             in_specs=(bspec, P(None, None), P(m, None, None),
                       P(m, None, None), P(m, None, None)),

@@ -313,7 +313,15 @@ def decode_step(params, cfg: ModelConfig, state, tokens):
                                   enc_out=enc_out)
             return h2, c2
 
-        x, new_caches = jax.lax.scan(body, x, (params["layers"], state["layers"]))
+        xs = (params["layers"], state["layers"])
+        # Under an explicit-axes mesh (jax.set_mesh) the body returns the
+        # carry typed with the sharding its layers give it (the seq-sharded
+        # decode's shard_map shards the batch); scan needs equal carry
+        # types, so the carry starts out with that sharding.
+        h_out, _ = jax.eval_shape(body, x, jax.tree.map(lambda a: a[0], xs))
+        if h_out.sharding is not None:
+            x = jax.sharding.reshard(x, h_out.sharding.spec)
+        x, new_caches = jax.lax.scan(body, x, xs)
         state = dict(state)
         state["layers"] = new_caches
     else:

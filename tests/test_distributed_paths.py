@@ -29,8 +29,6 @@ import dataclasses
 mesh = jax.make_mesh((2, 4), ("data", "model"))
 ctx = dist.MeshContext(mesh=mesh, batch_axes=("data",), model_axis="model")
 
-set_mesh = dist.set_mesh      # version-compat shim lives beside shard_map's
-
 # ---------- B/B2: MoE sharded vs local oracle ----------
 for E, name in [(8, "expert-parallel"), (2, "virtual-expert")]:
     cfg = dataclasses.replace(get_smoke_config("mixtral-8x22b"),
@@ -41,7 +39,7 @@ for E, name in [(8, "expert-parallel"), (2, "virtual-expert")]:
     x = jax.random.normal(jax.random.fold_in(key, 1), (4, 16, cfg.d_model))
     with dist.mesh_context(None):
         want, aux_want = moe_forward(p, cfg, x)
-    with dist.mesh_context(ctx), set_mesh(mesh):
+    with dist.mesh_context(ctx), jax.set_mesh(mesh):
         got, aux_got = jax.jit(lambda p_, x_: moe_forward(p_, cfg, x_))(p, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4, err_msg=name)
@@ -61,13 +59,13 @@ B, S = 2, 32
 tokens = jax.random.randint(jax.random.fold_in(key, 2), (B, S), 0,
                             cfg.vocab_size)
 
-def decode_all(use_mesh):
+def decode_all(sharded):
     state = T.init_decode_state(params, cfg, B, S)
     step = jax.jit(lambda p, s, t: T.decode_step(p, cfg, s, t))
     outs = []
     for t in range(S):
-        if use_mesh:
-            with dist.mesh_context(ctx), set_mesh(mesh):
+        if sharded:
+            with dist.mesh_context(ctx), jax.set_mesh(mesh):
                 logits, state = step(params, state, tokens[:, t:t+1])
         else:
             with dist.mesh_context(None):

@@ -22,8 +22,7 @@ from repro.fl.partition import partition  # noqa: E402
 from repro.fl.scenarios.engine import (DeadlineSimulator,  # noqa: E402
                                        LinkState)
 from repro.fl.scenarios.trace import _num, _unnum  # noqa: E402
-from repro.kernels.dequant_agg import dequant_fedagg  # noqa: E402
-from repro.kernels.fedagg import fedagg  # noqa: E402
+from repro.kernels.dequant_agg import dequant_fedagg, fedagg  # noqa: E402
 
 
 # ---------------------------------------------------------------------------

@@ -5,9 +5,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
+from repro.kernels import dequant_agg, ref
+from repro.kernels import ops as kops
 from repro.kernels.decode_attention import decode_attention
-from repro.kernels.fedagg import fedagg
+from repro.kernels.dequant_agg import dequant_fedagg, fedagg, float_fedagg
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.lora_matmul import lora_matmul
 
@@ -31,6 +32,84 @@ def test_fedagg_matches_ref(m, p, dtype):
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("kernel,dtype,m", [
+    ("fedagg", jnp.float32, 22),          # M tiles 8, 8, 6
+    ("float_fedagg", jnp.float32, 64),    # M tiles 8 x 8
+    ("float_fedagg", jnp.float16, 40),    # M tiles 14, 14, 12
+    ("dequant_fedagg", jnp.int8, 70),     # M tiles 24, 24, 22
+])
+def test_aggregation_kernels_tile_participants(kernel, dtype, m):
+    """At the default block the participant axis spans several sequential
+    M tiles, the last one ragged, and P needs padding."""
+    p = 3 * 65536 - 5
+    rows_per_tile = dequant_agg._TILE_BYTES // (
+        dequant_agg.SUBLANE_I8 * 2048 * jnp.dtype(dtype).itemsize)
+    assert m > rows_per_tile
+    key = jax.random.PRNGKey(m)
+    betas = jax.nn.softmax(jax.random.normal(jax.random.fold_in(key, 1), (m,)))
+    if kernel == "dequant_fedagg":
+        q = jax.random.randint(key, (m, p), -127, 128).astype(jnp.int8)
+        scales = jax.random.uniform(jax.random.fold_in(key, 2), (m,))
+        got = dequant_fedagg(q, scales, betas, interpret=True)
+        want = ref.dequant_fedagg(q, scales, betas)
+    else:
+        x = _rand(key, (m, p), dtype)
+        fn, rf = ((fedagg, ref.fedagg) if kernel == "fedagg"
+                  else (float_fedagg, ref.float_fedagg))
+        got, want = fn(x, betas, interpret=True), rf(x, betas)
+    want = np.asarray(want, np.float32)
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, rtol=0,
+                               atol=1e-6 * np.abs(want).max())
+
+
+def test_f16_bit_decode_is_exact_for_every_pattern():
+    bits = np.arange(1 << 16, dtype=np.uint16)
+    got = np.asarray(dequant_agg._f16_bits_to_f32(jnp.asarray(bits)))
+    want = bits.view(np.float16).astype(np.float32)
+    nan = np.isnan(want)
+    np.testing.assert_array_equal(np.isnan(got), nan)
+    np.testing.assert_array_equal(got[~nan].view(np.uint32),
+                                  want[~nan].view(np.uint32))
+
+
+def test_float_fedagg_f16_special_values_match_ref():
+    """Subnormals, ±0, ±inf, nan and the fp16 extremes through the kernel.
+    Each column has one non-zero row and β are powers of two, so every sum
+    is exact and the kernel must equal the reference bit for bit."""
+    special = np.array([2.0 ** -24, -(2.0 ** -24), 3 * 2.0 ** -24,
+                        2.0 ** -15, -(2.0 ** -14), 0.0, -0.0, np.inf,
+                        -np.inf, np.nan, 65504.0, -65504.0, 1.5, -0.333],
+                       np.float16)
+    m, p = 3, 4096
+    x = np.zeros((m, p), np.float16)
+    cols = np.arange(p)
+    x[cols % m, cols] = np.resize(special, p)
+    betas = jnp.asarray([0.5, 0.25, 0.25], jnp.float32)
+    got = np.asarray(float_fedagg(jnp.asarray(x), betas, interpret=True))
+    want = np.asarray(ref.float_fedagg(jnp.asarray(x), betas))
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("backend,agg,model", [("cpu", "off", "off"),
+                                               ("tpu", "on", "off")])
+def test_dispatch_default_follows_backend(monkeypatch, backend, agg, model):
+    """Without set_mode only the aggregation kernels follow the backend;
+    the model kernels (no backward pass) stay on the references."""
+    monkeypatch.setattr(kops, "_MODE", None)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert (kops.get_mode(), kops.model_mode()) == (agg, model)
+    assert not kops.use_pallas()
+
+
+def test_explicit_mode_wins_and_on_refuses_non_tpu(monkeypatch):
+    monkeypatch.setattr(kops, "_MODE", None)
+    kops.set_mode("interpret")
+    assert (kops.get_mode(), kops.model_mode()) == ("interpret", "interpret")
+    with pytest.raises(RuntimeError, match="cpu"):
+        kops.set_mode("on")
+    assert kops.get_mode() == "interpret"
 
 
 # ---------------------------------------------------------------------------

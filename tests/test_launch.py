@@ -59,15 +59,6 @@ def test_moe_active_params_smaller_than_total():
     assert dense.active_param_count() == dense.param_count()
 
 
-def _abstract_mesh(shape, names):
-    """AbstractMesh across JAX versions: >=0.5 takes (shape, names); 0.4.x
-    takes a tuple of (name, size) pairs."""
-    try:
-        return jax.sharding.AbstractMesh(shape, names)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(names, shape)))
-
-
 @pytest.mark.parametrize("arch", ["deepseek-v2-236b", "mixtral-8x22b",
                                   "qwen3-1.7b", "zamba2-1.2b",
                                   "seamless-m4t-large-v2"])
@@ -79,7 +70,7 @@ def test_param_pspecs_divisible(arch, multi):
     cfg = get_config(arch)
     shape = (2, 16, 16) if multi else (16, 16)
     names = ("pod", "data", "model") if multi else ("data", "model")
-    mesh = _abstract_mesh(shape, names)
+    mesh = jax.sharding.AbstractMesh(shape, names)
     params = jax.eval_shape(lambda k: T.init_params(k, cfg),
                             jax.random.PRNGKey(0))
     specs = param_pspecs(params, cfg, mesh)
