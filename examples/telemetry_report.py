@@ -12,19 +12,19 @@ tables ``python -m benchmarks.report run-report <log.ndjson>`` prints.
 
 ``--telemetry sketch`` records the same run through the bounded-memory
 sketch sink (PR 8) — byte totals stay bit-equal, distributions become
-ε-approximate quantiles; ``--trace spans.json`` additionally exports the
-phase timers as Perfetto-loadable Chrome trace-event JSON and verifies the
-spans telescope back to the report's phase gauges.
+ε-approximate quantiles; ``--trace DIR`` runs the job under the JAX
+profiler and writes its trace there: the ``phase.*`` spans (with round and
+client ids) beside the device's ops, as a Perfetto JSON.
 """
 from __future__ import annotations
 
 import argparse
+from pathlib import Path
 
 from repro.core.strategies import STRATEGIES
 from repro.fl.runtime import FFTConfig
 from repro.fl.toy import make_toy_runner
-from repro.obs import (load_report, reconcile, render_markdown,
-                       verify_trace)
+from repro.obs import load_report, reconcile, render_markdown
 
 
 def main() -> None:
@@ -44,7 +44,7 @@ def main() -> None:
                     choices=["full", "sketch"],
                     help="flight-recorder mode (sketch = bounded memory)")
     ap.add_argument("--trace", default=None,
-                    help="also export a Chrome trace-event JSON here")
+                    help="also write a profiler trace to this directory")
     args = ap.parse_args()
 
     strategy = args.strategy or ("fedauto" if args.mode == "sync"
@@ -79,9 +79,9 @@ def main() -> None:
               f"  {row['share'] * 100:5.1f}%")
 
     if args.trace:
-        stats = verify_trace(args.trace, runner.report)
-        print(f"\ntrace verified: {stats} → load {args.trace} in "
-              f"https://ui.perfetto.dev")
+        path = next(Path(args.trace).glob(
+            "plugins/profile/*/perfetto_trace.json.gz"))
+        print(f"\nprofiler trace: {path} → open in https://ui.perfetto.dev")
 
     md = render_markdown([reloaded])
     print("\n" + md)

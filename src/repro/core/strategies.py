@@ -76,9 +76,10 @@ def _phase(ctx, name: str):
     use it to split their aggregation between the weight solve
     (``phase.weight_solve``) and the pytree accumulate
     (``phase.accumulate``); both nest inside the loop's ``phase.aggregate``,
-    which (timers being exclusive) keeps only its own bookkeeping time."""
+    which (timers being exclusive) keeps only its own bookkeeping time.
+    Its program span carries the round."""
     tel = getattr(ctx, "telemetry", None)
-    return (tel or NULL_TELEMETRY).timer(name)
+    return (tel or NULL_TELEMETRY).timer(name, round=ctx.rnd)
 
 
 def _accumulate(ctx, models, betas):

@@ -212,7 +212,7 @@ class AdaptiveCommController:
         ``observe`` later divides the wire bits that actually traveled by
         the observed time."""
         tel = self.telemetry
-        with tel.timer("phase.controller"):
+        with tel.timer("phase.controller", round=rnd):
             idx_arr = self.rung_indices(self.cap_hat)
             a = RoundAssignment(
                 rnd=rnd,
@@ -252,7 +252,7 @@ class AdaptiveCommController:
         if a is None:
             return
         tel = self.telemetry
-        with tel.timer("phase.controller"):
+        with tel.timer("phase.controller", round=rnd):
             sel = np.asarray(selected, dtype=bool)
             finish = events.finish_array()
             met = events.deadline_mask()

@@ -313,7 +313,7 @@ class CommState:
         ``roundtrip`` (they are the same code); only the server-side
         reconstruction is omitted."""
         tel = self.telemetry
-        with tel.timer("phase.uplink"):
+        with tel.timer("phase.uplink", client=client):
             payload, decoded, distortion = self._encode(
                 client, model, global_params, codec)
             if tel:
@@ -365,7 +365,7 @@ class CommState:
         ``encode_upload`` alone and never build ``recon``.
         """
         tel = self.telemetry
-        with tel.timer("phase.uplink"):
+        with tel.timer("phase.uplink", client=client):
             payload, decoded, distortion = self._encode(
                 client, model, global_params, codec)
             recon = jax.tree.map(

@@ -218,26 +218,28 @@ class StreamAccumulator:
         entries = self._buckets.pop(fam, [])
         if not entries:
             return
-        self._ensure_acc()
-        betas = jnp.asarray([b for _, b in entries], jnp.float32)
-        payloads = [p for p, _ in entries]
-        mode = kops.get_mode()
-        for li, shape in enumerate(self._shapes):
-            els = [p.leaves[li] for p in payloads]
-            n = _size(shape)
-            if fam == "quant":
-                part = _quant_reduce([e.data["q"] for e in els],
-                                     [e.data["scale"] for e in els],
-                                     betas, mode=mode)
-            elif fam in ("fp16", "fp32"):
-                part = _float_reduce([e.data["v"] for e in els], betas,
-                                     mode=mode)
-            else:                                   # topk:<spec>
-                part = _topk_reduce([e.data["idx"] for e in els],
-                                    [e.data["val"] for e in els],
-                                    betas, n=n, mode=mode)
-            self._acc[li] = self._acc[li] + part
-            self._note_peak(4 * n)          # one batched partial leaf live
+        with self.telemetry.timer("phase.flush", family=fam,
+                                  payloads=len(entries)):
+            self._ensure_acc()
+            betas = jnp.asarray([b for _, b in entries], jnp.float32)
+            payloads = [p for p, _ in entries]
+            mode = kops.get_mode()
+            for li, shape in enumerate(self._shapes):
+                els = [p.leaves[li] for p in payloads]
+                n = _size(shape)
+                if fam == "quant":
+                    part = _quant_reduce([e.data["q"] for e in els],
+                                         [e.data["scale"] for e in els],
+                                         betas, mode=mode)
+                elif fam in ("fp16", "fp32"):
+                    part = _float_reduce([e.data["v"] for e in els], betas,
+                                         mode=mode)
+                else:                                   # topk:<spec>
+                    part = _topk_reduce([e.data["idx"] for e in els],
+                                        [e.data["val"] for e in els],
+                                        betas, n=n, mode=mode)
+                self._acc[li] = self._acc[li] + part
+                self._note_peak(4 * n)      # one batched partial leaf live
         self.n_fused += len(entries)
         self.n_flushes += 1
         if self.telemetry:

@@ -18,6 +18,7 @@ serves every participant.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Callable, List, Optional, Sequence
@@ -131,10 +132,11 @@ class FFTConfig:
     telemetry_health: bool = True         # online run-health monitors (when
     #                                       telemetry is on): alarm records +
     #                                       run-end verdict; observational
-    telemetry_trace: Optional[str] = None  # Chrome trace-event JSON path
-    #                                       (implies telemetry; open the file
-    #                                       in Perfetto for a flamegraph of
-    #                                       the phase timers)
+    telemetry_trace: Optional[str] = None  # directory: run() runs under
+    #                                       the JAX profiler and writes its
+    #                                       trace there (xplane + Perfetto
+    #                                       JSON: phase spans and device ops;
+    #                                       does not turn telemetry on)
     telemetry_dashboard: bool = False     # in-place live console dashboard
     #                                       (implies telemetry)
 
@@ -394,7 +396,7 @@ class FFTRunner:
 
     def run_local(self, t_global, x, y, rnd, *, mu=0.0, corr=None):
         tel = self.telemetry
-        with tel.timer("phase.local_update"):
+        with tel.timer("phase.local_update", round=rnd):
             corr = corr if corr is not None else self._zeros_like_t(t_global)
             out = self._local_update(t_global, t_global, corr, x, y,
                                      self._next_key(), self.lr(rnd), mu)
@@ -419,16 +421,18 @@ class FFTRunner:
 
     def train_compensatory(self, miss_mask: np.ndarray, rnd: int):
         """Module 1 (Eq. 6): E SGD steps on the missing-class public subset."""
-        miss_classes = np.where(miss_mask)[0]
-        sel = np.isin(np.asarray(self.public_y_raw), miss_classes)
-        idx = np.where(sel)[0]
-        if len(idx) == 0:
-            return None, None
-        res = self.rng.choice(idx, self.data_size, replace=True)
-        x = self.public_x_raw[res]
-        y = self.public_y_raw[res]
-        model = self.run_local(self.global_params, x, y, rnd)
-        hist = class_histogram(np.asarray(self.public_y_raw)[idx], self.n_classes)
+        with self.telemetry.timer("phase.compensatory", round=rnd):
+            miss_classes = np.where(miss_mask)[0]
+            sel = np.isin(np.asarray(self.public_y_raw), miss_classes)
+            idx = np.where(sel)[0]
+            if len(idx) == 0:
+                return None, None
+            res = self.rng.choice(idx, self.data_size, replace=True)
+            x = self.public_x_raw[res]
+            y = self.public_y_raw[res]
+            model = self.run_local(self.global_params, x, y, rnd)
+            hist = class_histogram(np.asarray(self.public_y_raw)[idx],
+                                   self.n_classes)
         return model, hist
 
     def pretrain(self, steps: int) -> None:
@@ -518,24 +522,35 @@ class FFTRunner:
         self.timeline: List[TimePoint] = []
         self.loop = make_round_loop(self.cfg.server_mode, self, strategy,
                                     tracer=tracer, log=log)
-        try:
-            return self.loop.run(rounds)
-        finally:
-            self.telemetry.end_run()
-            if tracer is not None:
-                tracer.close()
-            if self.controller is not None and self.cfg.controller_state_out:
-                self.controller.save_state(self.cfg.controller_state_out)
+        profile = contextlib.nullcontext()
+        if self.cfg.telemetry_trace:
+            # program spans and device ops; the Python tracer (every call)
+            # would slow the run and crowd the Perfetto file
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            profile = jax.profiler.trace(self.cfg.telemetry_trace,
+                                         create_perfetto_trace=True,
+                                         profiler_options=options)
+        with profile:
+            try:
+                return self.loop.run(rounds)
+            finally:
+                self.telemetry.end_run()
+                if tracer is not None:
+                    tracer.close()
+                if (self.controller is not None
+                        and self.cfg.controller_state_out):
+                    self.controller.save_state(
+                        self.cfg.controller_state_out)
 
     def _make_telemetry(self, strategy: Strategy, rounds: int):
         """Build this run's telemetry hub (a fresh one per run, like the
         error-feedback residuals) and attach it to every collaborator that
         emits into it.  Disabled (the default) this is the shared falsy
         no-op hub — zero per-round work, bit-identical histories."""
-        from repro.obs import (ChromeTraceRecorder, ConsoleSink,
-                               DashboardSink, HealthMonitors, NdjsonSink,
-                               NULL_TELEMETRY, RunReport, SketchReport,
-                               SketchState, Telemetry)
+        from repro.obs import (ConsoleSink, DashboardSink, HealthMonitors,
+                               NdjsonSink, NULL_TELEMETRY, RunReport,
+                               SketchReport, SketchState, Telemetry)
         cfg = self.cfg
         mode = cfg.telemetry
         if mode is True:
@@ -544,7 +559,7 @@ class FFTRunner:
             raise ValueError(f"FFTConfig.telemetry must be False, True, "
                              f"'full', or 'sketch', got {cfg.telemetry!r}")
         enabled = bool(mode or cfg.telemetry_log or cfg.telemetry_console
-                       or cfg.telemetry_trace or cfg.telemetry_dashboard)
+                       or cfg.telemetry_dashboard)
         if enabled:
             mode = mode or "full"
             sketch = None
@@ -565,10 +580,7 @@ class FFTRunner:
                 # after the report sink, so each frame sees the new round
                 sinks.append(DashboardSink(self.report))
             health = HealthMonitors() if cfg.telemetry_health else None
-            trace = (ChromeTraceRecorder(cfg.telemetry_trace)
-                     if cfg.telemetry_trace else None)
-            tel = Telemetry(sinks=sinks, sketch=sketch, health=health,
-                            trace=trace)
+            tel = Telemetry(sinks=sinks, sketch=sketch, health=health)
             tel.start_run({
                 "scenario": self.failure_mode_resolved,
                 "server_mode": cfg.server_mode,

@@ -85,7 +85,7 @@ class StalenessBuffer:
         enough (staleness ``<= tau_max``); silently evict updates whose
         staleness exceeded the horizon (landed or not — they can only get
         staler).  Returns arrivals sorted by landing time."""
-        with self.telemetry.timer("phase.buffer"):
+        with self.telemetry.timer("phase.buffer", round=current_round):
             ready, kept = [], []
             for e in self._entries:
                 if e.staleness(current_round) > self.tau_max:
@@ -115,7 +115,7 @@ class StalenessBuffer:
         """Drop every update whose staleness exceeded the horizon; returns
         the number evicted.  ``collect`` does this implicitly — this is for
         rounds where the server defers aggregation."""
-        with self.telemetry.timer("phase.buffer"):
+        with self.telemetry.timer("phase.buffer", round=current_round):
             n0 = len(self._entries)
             if self.telemetry:
                 for e in self._entries:

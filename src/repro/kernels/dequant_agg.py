@@ -99,10 +99,11 @@ def _kernel(coef_ref, x_ref, o_ref, *, m_total, bm, chunk, decode):
         o_ref[:, cols] = jax.lax.fori_loop(0, n_valid, body, o_ref[:, cols])
 
 
-def _coef_reduce(x: jax.Array, coef: jax.Array, *, block: int,
+def _coef_reduce(x: jax.Array, coef: jax.Array, *, name: str, block: int,
                  interpret: bool) -> jax.Array:
     """Shared host-side wrapper: tile the (M, P) payload matrix and run the
-    coefficient-weighted in-tile decode+reduce, (P,) fp32 out."""
+    coefficient-weighted in-tile decode+reduce, (P,) fp32 out.  ``name``
+    is the kernel's name in the compiled program and the device trace."""
     decode = _to_f32
     if x.dtype == jnp.float16:
         x = jax.lax.bitcast_convert_type(x, jnp.uint16)
@@ -133,6 +134,7 @@ def _coef_reduce(x: jax.Array, coef: jax.Array, *, block: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name=name,
     )(coef, x3)
     return out.reshape(P_pad)[:P]
 
@@ -142,15 +144,16 @@ def dequant_fedagg(q: jax.Array, scales: jax.Array, betas: jax.Array, *,
                    block: int = 2048, interpret: bool = False) -> jax.Array:
     """q: (M, P) int8; scales, betas: (M,) -> (P,) fp32 = Σ_m β_m s_m q[m]."""
     coef = betas.astype(jnp.float32) * scales.astype(jnp.float32)
-    return _coef_reduce(q, coef, block=block, interpret=interpret)
+    return _coef_reduce(q, coef, name="dequant_fedagg", block=block,
+                        interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def float_fedagg(x: jax.Array, betas: jax.Array, *,
                  block: int = 2048, interpret: bool = False) -> jax.Array:
     """x: (M, P) fp16/bf16/fp32; betas: (M,) -> (P,) fp32 = Σ_m β_m x[m]."""
-    return _coef_reduce(x, betas.astype(jnp.float32), block=block,
-                        interpret=interpret)
+    return _coef_reduce(x, betas.astype(jnp.float32), name="float_fedagg",
+                        block=block, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))

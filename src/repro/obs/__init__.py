@@ -14,15 +14,13 @@ Population-scale additions (PR 8): ``telemetry="sketch"`` swaps the
 per-client rows for bounded-memory streaming sketches (``SketchReport``,
 exact additive totals, ε-approximate quantiles, K-row reservoir);
 ``HealthMonitors`` watch the round stream online and emit schema'd alarm
-records plus a run-end verdict; ``telemetry_trace=<path>`` exports the
-phase timers as Perfetto-loadable Chrome trace-event JSON; and
-``telemetry_dashboard=True`` / ``benchmarks.report watch`` render a live
-in-place run dashboard.  ``load_report`` picks the right report type for
+records plus a run-end verdict; ``telemetry_trace=<dir>`` runs the job
+under the JAX profiler, whose trace holds every ``phase.*`` span (the
+timers are ``TraceAnnotation``s, on with or without telemetry) beside the
+device's ops, and opens in Perfetto; and ``telemetry_dashboard=True`` /
+``benchmarks.report watch`` render a live in-place run dashboard.  ``load_report`` picks the right report type for
 any NDJSON log.
 """
-from repro.obs.chrometrace import (ChromeTraceError,  # noqa: F401
-                                   ChromeTraceRecorder, load_trace,
-                                   self_times, verify_trace)
 from repro.obs.dashboard import (DashboardSink,  # noqa: F401
                                  render_dashboard, sparkline, watch)
 from repro.obs.health import (HealthConfig, HealthMonitors,  # noqa: F401

@@ -37,11 +37,19 @@ path is a shared ``NULL_TELEMETRY`` no-op whose methods do nothing and
 which is *falsy* — instrumentation sites guard any record-building work
 with ``if tel:`` so a telemetry-off run executes no extra code beyond the
 no-op call itself.
+
+Timers are also program spans: ``timer(name, **ids)`` on either hub enters
+a ``jax.profiler.TraceAnnotation(name, **ids)``, so every ``phase.*`` phase
+lands in a profiler trace (on the device trace's clock, with its ids as
+event stats) whether or not telemetry is on.  With the profiler off an
+annotation costs about a microsecond.
 """
 from __future__ import annotations
 
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 # ---------------------------------------------------------------------------
 # drop-cause / outcome vocabulary
@@ -87,19 +95,6 @@ def beta_row(beta: float, *, role: str = "client",
     return row
 
 
-class _NullTimer:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_TIMER = _NullTimer()
-
-
 class NullTelemetry:
     """Disabled telemetry: every method is a no-op and the object is falsy,
     so ``if tel:``-guarded record building never runs.  One shared instance
@@ -137,8 +132,9 @@ class NullTelemetry:
     def counter(self, name: str, inc: float = 1) -> None:
         pass
 
-    def timer(self, name: str):
-        return _NULL_TIMER
+    def timer(self, name: str, **ids):
+        """The phase's program span alone (no clock is read)."""
+        return TraceAnnotation(name, **ids)
 
     def end_round(self, rnd: int) -> None:
         pass
@@ -151,23 +147,26 @@ NULL_TELEMETRY = NullTelemetry()
 
 
 class _Timer:
-    """Exclusive (self-time) phase timer.
+    """Exclusive (self-time) phase timer, inside its program span.
 
     Timers nest: entering a timer while another is active *pauses* the
     outer one, so each phase accumulates only the time no inner phase
     claimed.  Disjoint-by-construction means per-round phase seconds sum
     to at most the round's wall time, never more — ``phase.local_update``
     triggered from inside a strategy's aggregation step is attributed to
-    the local update, not double-counted under ``phase.aggregate``.
+    the local update, not double-counted under ``phase.aggregate``.  The
+    profiler's self time of the span equals the timer's share.
     """
 
-    __slots__ = ("_tel", "_name")
+    __slots__ = ("_tel", "_name", "_span")
 
-    def __init__(self, tel: "Telemetry", name: str):
+    def __init__(self, tel: "Telemetry", name: str, ids):
         self._tel = tel
         self._name = name
+        self._span = TraceAnnotation(name, **ids)
 
     def __enter__(self):
+        self._span.__enter__()
         now = time.perf_counter()
         stack = self._tel._timer_stack
         if stack:                          # pause the enclosing phase
@@ -175,12 +174,6 @@ class _Timer:
             timers = self._tel.timers_s
             timers[outer[0]] = timers.get(outer[0], 0.0) + (now - outer[1])
         stack.append([self._name, now])
-        trace = self._tel.trace
-        if trace is not None:
-            # the *same* timestamp feeds the timer accounting and the trace
-            # span, so a self-time replay of the trace reproduces the
-            # exclusive timers bit-for-bit
-            trace.begin(self._name, now)
         return self
 
     def __exit__(self, *exc):
@@ -191,9 +184,7 @@ class _Timer:
         timers[name] = timers.get(name, 0.0) + (now - t0)
         if stack:                          # resume the enclosing phase
             stack[-1][1] = now
-        trace = self._tel.trace
-        if trace is not None:
-            trace.end(name, now)
+        self._span.__exit__(*exc)
         return False
 
 
@@ -212,11 +203,10 @@ class Telemetry:
 
     enabled = True
 
-    def __init__(self, sinks=(), *, sketch=None, health=None, trace=None):
+    def __init__(self, sinks=(), *, sketch=None, health=None):
         self.sinks = list(sinks)
         self.sketch = sketch           # SketchState → bounded-memory mode
         self.health = health           # HealthMonitors → online detectors
-        self.trace = trace             # ChromeTraceRecorder → span export
         self.meta: Dict[str, Any] = {}
         self.counters: Dict[str, float] = {}
         self.timers_s: Dict[str, float] = {}
@@ -243,9 +233,6 @@ class Telemetry:
         else:
             self._round = {"round": int(rnd), "clients": {}, "gauges": {},
                            "betas": []}
-        if self.trace is not None:
-            self.trace.begin("round", time.perf_counter(),
-                             args={"round": int(rnd)})
 
     def _staged(self, rnd: int) -> Dict[str, Any]:
         if self._round is None or self._round["round"] != int(rnd):
@@ -323,14 +310,15 @@ class Telemetry:
     def counter(self, name: str, inc: float = 1) -> None:
         self.counters[name] = self.counters.get(name, 0.0) + inc
 
-    def timer(self, name: str) -> _Timer:
+    def timer(self, name: str, **ids) -> _Timer:
         """Context manager accumulating *exclusive* wall seconds into
-        ``timers_s[name]`` (nested timers pause the enclosing one).  Names
+        ``timers_s[name]`` (nested timers pause the enclosing one), inside
+        the program span ``name`` with ``ids`` as its stats.  Names
         prefixed ``phase.`` are the per-round profiler phases: the round
         loops emit each round's delta as a same-named gauge, so phase
         seconds land in the ``RunReport`` / NDJSON log per round and
         ``RunReport.phase_table()`` can break a run down by phase."""
-        return _Timer(self, name)
+        return _Timer(self, name, ids)
 
     # ------------------------------------------------------------- flushing
     def end_round(self, rnd: int) -> None:
@@ -342,8 +330,6 @@ class Telemetry:
             ess = _beta_ess_from_rows(staged["betas"])
             if ess is not None:
                 staged["gauges"]["beta_ess"] = ess
-        if self.trace is not None:
-            self.trace.end("round", time.perf_counter())
         for s in self.sinks:
             s.on_round(staged)
         if self.health is not None:
@@ -364,8 +350,6 @@ class Telemetry:
             summary["health"] = self.health.verdict()
         for s in self.sinks:
             s.on_run_end(summary)
-        if self.trace is not None:
-            self.trace.save(meta=self.meta)
 
 
 def _beta_ess_from_rows(rows: List[Dict[str, Any]]) -> Optional[float]:

@@ -1,12 +1,12 @@
 """Population-scale telemetry (PR 8): sketch sinks, health monitors,
-Chrome-trace export, dashboard, and the crash-durability satellites.
+dashboard, and the crash-durability satellites.
 
 Deterministic variants of the sketch-accuracy properties live here (the
 hypothesis sweeps are in ``test_hypothesis_properties.py``); the heavy
 claims are structural: sketch-mode totals bit-equal to full mode on the
 same seeded run, resident telemetry state O(rounds + K) at 50k clients,
-trace spans telescoping to the phase gauges, and health monitors firing on
-the seeded blackout world while staying silent on the healthy baselines.
+and health monitors firing on the seeded blackout world while staying
+silent on the healthy baselines.
 """
 import io
 import json
@@ -22,11 +22,10 @@ from repro.core.strategies import STRATEGIES
 from repro.fl.runtime import FFTConfig
 from repro.fl.toy import make_toy_runner
 from repro.obs import (AGGREGATED, EVICTED, LINK_DOWN, NOT_SELECTED,
-                       ChromeTraceError, ExactSum, GKQuantiles,
-                       HealthConfig, HealthMonitors, NdjsonSink, Reservoir,
-                       RunReport, SketchReport, SketchState, Telemetry,
-                       beta_row, load_report, reconcile, render_dashboard,
-                       render_markdown, verify_trace, watch)
+                       ExactSum, GKQuantiles, HealthConfig, HealthMonitors,
+                       NdjsonSink, Reservoir, RunReport, SketchReport,
+                       SketchState, Telemetry, beta_row, load_report,
+                       reconcile, render_dashboard, render_markdown, watch)
 
 BASE = dict(n_clients=6, k_selected=4, local_steps=2, batch_size=8, lr=0.05,
             seed=3, eval_every=2, deadline_s=30.0, tau_max=3, buffer_k=2,
@@ -39,15 +38,13 @@ ROUNDS = 5
 @pytest.fixture(scope="module")
 def mode_runs(tmp_path_factory):
     """The same seeded buffered-adaptive run recorded twice: once in full
-    mode (with NDJSON log and Chrome trace), once in sketch mode."""
+    mode, once in sketch mode (each with an NDJSON log)."""
     tmp = tmp_path_factory.mktemp("obs_scale")
     out = {}
     for mode in ("full", "sketch"):
         cfg = FFTConfig(**BASE, server_mode="buffered",
                         codec="adaptive:sign1-fp16", telemetry=mode,
-                        telemetry_log=str(tmp / f"{mode}.ndjson"),
-                        telemetry_trace=(str(tmp / "trace.json")
-                                         if mode == "full" else None))
+                        telemetry_log=str(tmp / f"{mode}.ndjson"))
         runner = make_toy_runner(cfg, **TOY)
         hist = runner.run(STRATEGIES["fedauto_async"](), rounds=ROUNDS)
         out[mode] = (runner, hist)
@@ -307,33 +304,6 @@ def test_population_scale_sketch_smoke():
     tel.client_outcome(1, 3, NOT_SELECTED)
     with pytest.raises(ValueError, match="exactly one terminal outcome"):
         tel.client_outcome(1, 3, AGGREGATED)
-
-
-# ---------------------------------------------------------------------------
-# Chrome-trace export
-# ---------------------------------------------------------------------------
-def test_trace_is_valid_and_telescopes(mode_runs):
-    runner, _ = mode_runs["full"]
-    path = runner.cfg.telemetry_trace
-    doc = json.load(open(path))
-    assert isinstance(doc["traceEvents"], list) and doc["traceEvents"]
-    assert {e["ph"] for e in doc["traceEvents"]} == {"B", "E"}
-    assert all(e["ts"] >= 0 for e in doc["traceEvents"])
-    stats = verify_trace(path, runner.report)
-    assert stats["rounds_checked"] == ROUNDS
-    assert stats["timers_checked"] == len(runner.report.summary["timers_s"])
-
-
-def test_trace_verification_catches_tampering(mode_runs, tmp_path):
-    runner, _ = mode_runs["full"]
-    doc = json.load(open(runner.cfg.telemetry_trace))
-    phase_ev = next(e for e in doc["traceEvents"]
-                    if e["name"].startswith("phase.") and e["ph"] == "E")
-    phase_ev["ts"] += 5e6                  # stretch one span by 5 seconds
-    bad = tmp_path / "tampered.json"
-    bad.write_text(json.dumps(doc))
-    with pytest.raises((ChromeTraceError, ValueError)):
-        verify_trace(str(bad), runner.report)
 
 
 # ---------------------------------------------------------------------------

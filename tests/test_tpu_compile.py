@@ -5,9 +5,12 @@ attached) v5e topology.  Interpret-mode tests cannot see what only Mosaic
 refuses — VMEM exhaustion and vector types the chip cannot load — so each
 case compiles the kernel at ResNet-18's largest leaf (512·512·3·3 params)
 with the participant counts the server produces: M = K+2 = 22 on the
-materializing path and M = 64, the streaming accumulator's batch.
+materializing path and M = 64, the streaming accumulator's batch.  Each
+kernel keeps its own name in the compiled program (``pallas_call``'s
+``name``), which is how the device trace's op list shows it.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -73,5 +76,9 @@ def test_aggregation_kernel_compiles_for_v5e(one_chip, no_persistent_cache,
     else:
         fn = fedagg if kernel == "fedagg" else float_fedagg
         lowered = fn.lower(x, betas)
-    compiled = lowered.compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    name = "dequant_fedagg" if kernel == "dequant_fedagg" else "float_fedagg"
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    assert calls and all(re.search(rf'op_name="[^"]*/{name}/pallas_call"', l)
+                         for l in calls), calls
