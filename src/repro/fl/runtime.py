@@ -355,9 +355,13 @@ class FFTRunner:
                 idx = jax.random.randint(k, (bs,), 0, n)
                 g = jax.grad(loss_t)(tt, x[idx], y[idx])
                 g = jax.tree.map(
-                    lambda gg, p_, pg, c: gg.astype(jnp.float32) +
-                    mu * (p_.astype(jnp.float32) - pg.astype(jnp.float32)) + c,
-                    g, tt, t_global, corr)
+                    lambda gg, p_, pg: gg.astype(jnp.float32) +
+                    mu * (p_.astype(jnp.float32) - pg.astype(jnp.float32)),
+                    g, tt, t_global)
+                # corr None (an empty pytree) traces a program of its own
+                # with no correction term; SCAFFOLD's tree is added here
+                if corr is not None:
+                    g = jax.tree.map(jnp.add, g, corr)
                 tt = jax.tree.map(lambda p_, gg: (p_.astype(jnp.float32) -
                                                   lr * gg).astype(p_.dtype), tt, g)
                 return tt, None
@@ -387,9 +391,6 @@ class FFTRunner:
             return self.cfg.lr * 0.1
         return self.cfg.lr
 
-    def _zeros_like_t(self, t):
-        return jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), t)
-
     def _next_key(self):
         self._key, k = jax.random.split(self._key)
         return k
@@ -397,10 +398,12 @@ class FFTRunner:
     def run_local(self, t_global, x, y, rnd, *, mu=0.0, corr=None):
         tel = self.telemetry
         with tel.timer("phase.local_update", round=rnd):
-            corr = corr if corr is not None else self._zeros_like_t(t_global)
             out = self._local_update(t_global, t_global, corr, x, y,
                                      self._next_key(), self.lr(rnd), mu)
             if tel:
+                tel.counter("local_update.calls")
+                if corr is not None:
+                    tel.counter("local_update.corrected")
                 # the update is one jitted lax.scan: without a sync the timer
                 # would stop at dispatch, not completion
                 jax.block_until_ready(out)
