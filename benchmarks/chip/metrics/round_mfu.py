@@ -1,5 +1,5 @@
-"""Model FLOPs of every local update in the traced window (the
-configuration's analytic training FLOPs per sample x E x B per update), over
+"""Model FLOPs of every local update in the traced window (the task's
+training FLOPs per sample, an image or a sequence, x E x B per update), over
 the window times the chip's bf16 peak: the whole round's share of the peak.
 fp32 matmuls at the TPU's default precision run on the bf16 MXU path."""
 

@@ -52,7 +52,8 @@ def readings(args) -> int:
             t1 = time.perf_counter()
             with jax.default_matmul_precision("highest"):
                 ws = reference.follow(
-                    keep["mod"], keep["sizes"], keep["base"], keep["w0"], keep["rounds"],
+                    keep["mod"], keep["sizes"], keep["task"], keep["base"], keep["w0"],
+                    keep["rounds"],
                     server_hist=keep["hists"][0], client_hists=keep["hists"][1],
                     public_y=keep["public_y"], steps=keep["fft"]["local_steps"],
                     batch=keep["fft"]["batch_size"], dtype=dtype, fault=fault)

@@ -6,15 +6,16 @@ inputs the round drew: which clients connected, and for each local update the
 rows it trains on and the key its minibatches come from. From the benchmark's
 own weights it then follows the rounds itself:
 
-* each participant's local update: E steps of minibatch SGD on the mean
-  cross-entropy, minibatch rows ``randint(split(key, E)[s], (B,), 0, n)``,
-  through the configuration's own forward (float32, highest matmul
-  precision);
+* each participant's local update: E steps of minibatch SGD on the task's
+  mean loss (``tasks/<name>.py``), minibatch rows ``randint(split(key,
+  E)[s], (B,), 0, n)``, through the configuration's own forward (float32,
+  highest matmul precision);
 * the upload: lossless for ``fp32``, so the server holds the client's model;
 * the FedAuto weights (paper Eq. 8-9): the server pinned to 1/(1+m), the rest
-  on the simplex minimising the chi-square gap of the class mixture, by the
-  solver the program states (400 FISTA steps from the uniform start), here in
-  float64 on the host;
+  on the simplex minimising the chi-square gap of the mixture of the task's
+  histogram bins, by the solver the program states (400 FISTA steps from the
+  uniform start), here in float64 on the host; the compensatory update's row
+  is the task's ``missing_hist`` over the bins no participant holds;
 * the aggregate: the beta-weighted sum of the participants' models.
 
 Each number compared is a worst-leaf gap of change norms: for every leaf,
@@ -58,32 +59,33 @@ class Round:
     lr: float
 
 
-def local_update_fn(mod, sizes, steps: int, batch: int, dtype, fault: Optional[str]):
+def local_update_fn(mod, sizes, task, steps: int, batch: int, dtype, fault: Optional[str]):
     """jit(base, trainable, x, y, key, lr) -> trainable after E SGD steps;
-    one per configuration and variant in a process, so that seeds after the
-    first reuse its compilation."""
-    key = (id(mod), json.dumps(sizes, sort_keys=True), steps, batch, jnp.dtype(dtype).name, fault)
+    one per configuration, task and variant in a process, so that seeds after
+    the first reuse its compilation."""
+    key = (id(mod), json.dumps(sizes, sort_keys=True), id(task), steps, batch,
+           jnp.dtype(dtype).name, fault)
     if key not in _LOCAL_UPDATES:
-        _LOCAL_UPDATES[key] = (mod, _local_update_fn(mod, sizes, steps, batch, dtype, fault))
-    return _LOCAL_UPDATES[key][1]
+        _LOCAL_UPDATES[key] = (mod, task, _local_update_fn(mod, sizes, task, steps, batch,
+                                                          dtype, fault))
+    return _LOCAL_UPDATES[key][2]
 
 
 _LOCAL_UPDATES: Dict[tuple, tuple] = {}
 
 
-def _local_update_fn(mod, sizes, steps, batch, dtype, fault):
+def _local_update_fn(mod, sizes, task, steps, batch, dtype, fault):
     keep = batch // 2 if fault == "half_minibatch" else batch
 
     def loss(t, base, xb, yb):
-        logits = mod.reference_logits(sizes, base, t, xb).astype(dtype)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        return -jnp.mean(jnp.take_along_axis(logp, yb[:, None], axis=1))
+        return task.loss(mod.reference_logits(sizes, base, t, xb).astype(dtype), yb)
 
     @jax.jit
     def run(base, t, x, y, key, lr):
         base = jax.tree.map(lambda a: a.astype(dtype), base)
         t = jax.tree.map(lambda a: a.astype(dtype), t)
-        x = x.astype(dtype)
+        if jnp.issubdtype(x.dtype, jnp.floating):       # token ids stay integers
+            x = x.astype(dtype)
         lr = jnp.asarray(lr, dtype)
         def step(t, k):
             idx = jax.random.randint(k, (batch,), 0, x.shape[0])[:keep]
@@ -137,11 +139,11 @@ def _dist(h):
     return h / s if s > 0 else np.full(len(h), 1.0 / len(h))
 
 
-def follow(mod, sizes, base, w0, rounds: Sequence[Round], *, server_hist,
+def follow(mod, sizes, task, base, w0, rounds: Sequence[Round], *, server_hist,
            client_hists, public_y, steps: int, batch: int,
            dtype=jnp.float32, fault: Optional[str] = None) -> List[Any]:
     """The global trainable tree after each of ``rounds``, from ``w0``."""
-    update = local_update_fn(mod, sizes, steps, batch, dtype, fault)
+    update = local_update_fn(mod, sizes, task, steps, batch, dtype, fault)
     target = _dist(server_hist + client_hists.sum(axis=0))
     w = jax.tree.map(lambda a: jnp.asarray(a, dtype), w0)
     out = []
@@ -158,8 +160,7 @@ def follow(mod, sizes, base, w0, rounds: Sequence[Round], *, server_hist,
         if not covered.all() and comp:
             c = comp[0]
             models.append(update(base, w, c.x, c.y, c.key, rnd.lr))
-            rows.append(_dist(np.bincount(public_y[np.isin(public_y, np.where(~covered)[0])],
-                                          minlength=len(target))))
+            rows.append(_dist(task.missing_hist(public_y, np.where(~covered)[0], len(target))))
         for i, cid in enumerate(clients):
             u = by_client[cid]
             m = update(base, w, u.x, u.y, u.key, rnd.lr)

@@ -4,9 +4,11 @@
     python3 benchmarks/chip/run.py --workload <cell> --seed N --seconds S --trace 0|1
 
 A cell of ``BENCHMARK.json`` names a configuration (``configs/<name>.json``
-and its module) and a traffic mix (``traffic/<name>.json``). The run makes
-the data and the weights from ``--seed`` on the device, builds the program's
-``FFTRunner``, makes the run's loop with ``FFTRunner.run(strategy,
+and its module) and a traffic mix (``traffic/<name>.json``); the
+configuration names its task (``"task"``: ``tasks/<name>.py``, with ``-``
+read as ``_``), which makes the job, its histograms and the reference's loss.
+The run makes the data and the weights from ``--seed`` on the device, builds
+the program's ``FFTRunner``, makes the run's loop with ``FFTRunner.run(strategy,
 rounds=0)`` and drives it with ``run_round`` through the traffic's warm-up
 rounds (set-up), then on until ``--seconds`` have passed (the window). ``round_s`` is the window's wall time
 over the rounds it completed, ending after ``block_until_ready`` on the
@@ -94,6 +96,15 @@ def cell_metrics(bench: Dict[str, Any], cell_name: str, here: Path = HERE):
         if cell_name in m.get("workloads", [cell_name]):
             out.append((m["name"], m["unit"], load_module(here / "metrics" / f"{m['name']}.py")))
     return out
+
+
+def load_task(name: str, here: Path = HERE):
+    """The module of the task a configuration names: ``tasks/<name>.py``,
+    with ``-`` read as ``_``; an unknown name lists the known ones."""
+    known = sorted(p.stem.replace("_", "-") for p in (here / "tasks").glob("*.py"))
+    if name not in known:
+        raise ValueError(f"unknown task {name!r} (known: {known})")
+    return load_module(here / "tasks" / f"{name.replace('-', '_')}.py")
 
 
 def load_limits(cell_name: str, here: Path = HERE) -> Dict[str, float]:
@@ -268,6 +279,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     counter = CompileCounter.get()
     csizes, mod = load_config(centry)
     sizes = sizes or csizes
+    task = load_task(sizes["task"])
     traffic = traffic or traffic_gen.load_traffic(cell["traffic"])
     fft = traffic["fft"]
     warm = int(traffic["warmup_rounds"])
@@ -277,7 +289,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     # ---- set-up: data and weights from the seed, the runner, the warm-up
     key = traffic_gen.seed_key(seed)
     k_data, k_model = jax.random.split(key)
-    job = traffic_gen.make_job(traffic, sizes, k_data)
+    job = task.make_job(traffic, sizes, k_data)
     base, w0 = init_fn(mod, sizes)(k_model)
     prog = mod.program(sizes)
     cfg = FFTConfig(**fft, seed=int(traffic["network_seed"]), eval_every=10 ** 9,
@@ -371,7 +383,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         ctx = TraceCtx(trace=tr, lo=lo, hi=hi, window_s=(hi - lo) * 1e-9, busy_s=busy * 1e-9,
                        rounds=len(round_times), peaks=load_peaks(dev.device_kind),
                        samples_per_update=fft["local_steps"] * fft["batch_size"],
-                       train_flops_per_sample=mod.train_flops(sizes),
+                       train_flops_per_sample=task.flops_per_sample(mod, sizes, traffic),
                        uplink_bytes=uplink_bytes, global_bytes=global_bytes)
         for name, unit, reader in cell_metrics(bench, workload):
             value = reader.read(ctx)
@@ -388,19 +400,19 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
                 metrics[m["name"]] = {"value": by_name[m["name"]], "unit": m["unit"]}
 
     # ---- correctness, once the program's state is freed
-    hists = traffic_gen.histograms(job)
+    hists = task.histograms(job)
     ref_rounds = [reference.Round(updates=u, lr=lr) for _, u, lr in rounds_in[:3]]
     public_y = np.asarray(job.public.y)
     del runner, loop, strategy, rec
     gc.collect()
     t_ref = time.perf_counter()
     with jax.default_matmul_precision("highest"):
-        ref_ws = reference.follow(mod, sizes, base, w0, ref_rounds, server_hist=hists[0],
+        ref_ws = reference.follow(mod, sizes, task, base, w0, ref_rounds, server_hist=hists[0],
                                   client_hists=hists[1], public_y=public_y,
                                   steps=fft["local_steps"], batch=fft["batch_size"])
     numbers = reference.compare(w0, prog_ws, ref_ws)
     if keep is not None:
-        keep.update(mod=mod, sizes=sizes, base=base, w0=w0, rounds=ref_rounds,
+        keep.update(mod=mod, sizes=sizes, task=task, base=base, w0=w0, rounds=ref_rounds,
                     hists=hists, public_y=public_y, fft=fft, prog_ws=prog_ws,
                     ref_ws=ref_ws, numbers=numbers)
     log(f"reference: 3 rounds in {time.perf_counter() - t_ref:.1f} s; "

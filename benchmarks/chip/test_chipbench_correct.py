@@ -1,6 +1,7 @@
 """``correct`` on the CPU at a tiny size: the timed path agrees with the plain
 reference, and comes out not correct when the timed path is broken
-underneath or the reference runs in bfloat16 (the control).
+underneath or the reference runs in bfloat16 (the control). The image task's
+job and the reference's numbers at that size are pinned.
 
 The harness's look for a chip is skipped (``rehearsal``); everything else of
 a run is driven: data and weights from the seed, the program's runner, the
@@ -10,6 +11,7 @@ import copy
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 import reference
@@ -46,11 +48,51 @@ def test_timed_path_agrees_and_the_control_does_not():
     assert res["attempted"] == 1 and res["failed"] == 0
     assert list(res)[-1] == "checks"
     limits = run.load_limits(CELL)
-    ws = reference.follow(keep["mod"], keep["sizes"], keep["base"], keep["w0"], keep["rounds"],
+    ws = reference.follow(keep["mod"], keep["sizes"], keep["task"], keep["base"], keep["w0"],
+                          keep["rounds"],
                           server_hist=keep["hists"][0], client_hists=keep["hists"][1],
                           public_y=keep["public_y"], steps=2, batch=8, dtype=jnp.bfloat16)
     control = reference.compare(keep["w0"], ws, keep["ref_ws"])
     assert any(control[k] > limits[k] for k in limits), control
+
+
+# The tiny run's job and numbers, from the harness before the task moved out
+# of it: the task must give the same job and the reference the same rounds.
+PINNED = {
+    "server_hist": [2] * 10,
+    "client_hists": [[4] * 10] * 4,
+    "x_shape": [196, 192],
+    "x_sumsq": 71012.10457179809,
+    "x_wsum": 258.33910454058423,
+    "change_1": 6.1637376071912496e-06,
+    "change_3": 3.7042671835001356e-06,
+    "ref_change_norms": [0.12299805940537338, 0.21985883892780042, 0.3017655455550273],
+}
+
+
+def test_image_task_is_unchanged():
+    sizes, traffic = _tiny()
+    assert sizes["task"] == "image-classes"
+    task = run.load_task(sizes["task"])
+    job = task.make_job(traffic, sizes, jax.random.split(workload.seed_key(SEED))[0])
+    server, clients = task.histograms(job)
+    assert server.tolist() == PINNED["server_hist"]
+    assert clients.tolist() == PINNED["client_hists"]
+    x = np.concatenate([np.asarray(s.x, np.float64).reshape(len(s.y), -1)
+                        for s in (job.public, job.private, job.test)])
+    weights = np.arange(x.size, dtype=np.float64).reshape(x.shape) % 7 - 3
+    assert list(x.shape) == PINNED["x_shape"]
+    assert np.sum(x * x) == pytest.approx(PINNED["x_sumsq"], rel=1e-6)
+    assert np.sum(x * weights) == pytest.approx(PINNED["x_wsum"], rel=1e-6)
+
+    keep = {}
+    assert _run(keep=keep)["correct"]
+    for name in ("change_1", "change_3"):
+        assert keep["numbers"][name] == pytest.approx(PINNED[name], rel=1e-6)
+    norms = [float(np.sqrt(sum(np.sum((np.asarray(a, np.float64) - np.asarray(b)) ** 2)
+                               for a, b in zip(jax.tree.leaves(w), jax.tree.leaves(keep["w0"])))))
+             for w in keep["ref_ws"]]
+    assert norms == pytest.approx(PINNED["ref_change_norms"], rel=1e-6)
 
 
 def _wrap_local_update(runner, fn):

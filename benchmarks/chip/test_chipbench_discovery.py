@@ -1,5 +1,5 @@
-"""The harness finds configurations, traffic mixes, cells and per-layer
-metrics by name, so that adding one takes a new file and no edit."""
+"""The harness finds configurations, their tasks, traffic mixes, cells and
+per-layer metrics by name, so that adding one takes a new file and no edit."""
 import json
 import shutil
 import subprocess
@@ -23,6 +23,9 @@ def test_every_cell_resolves():
         assert sizes["name"] == entry["name"]
         for fn in ("init", "program", "reference_logits", "forward_flops", "train_flops"):
             assert callable(getattr(mod, fn))
+        task = run.load_task(sizes["task"])
+        for fn in ("make_job", "histograms", "loss", "missing_hist", "flops_per_sample"):
+            assert callable(getattr(task, fn))
         traffic = workload.load_traffic(cell["traffic"])
         assert traffic["warmup_rounds"] >= 3
         assert set(run.load_limits(cell["name"])) == {"change_1", "change_3"}
@@ -71,6 +74,71 @@ def test_new_files_are_found_without_an_edit(tmp_path):
     names = [m[0] for m in run.cell_metrics(got, cell["name"], here)]
     assert "rounds_traced" in names
     assert "rounds_traced" not in [m[0] for m in run.cell_metrics(got, "resnet18-c100.sync-fp32", here)]
+
+
+TOY_LM = """
+import jax
+import jax.numpy as jnp
+
+
+def init(sizes, key):
+    v, d = sizes["vocab_size"], sizes["hidden_size"]
+    return {}, {"emb": jax.random.normal(key, (v, d)), "head": jnp.zeros((d, v))}
+
+
+def reference_logits(sizes, base, trainable, x):
+    return trainable["emb"][x] @ trainable["head"]
+
+
+def train_flops(sizes, seq_len):
+    return 6.0 * sizes["vocab_size"] * sizes["hidden_size"] * seq_len
+"""
+
+
+def test_a_causal_lm_configuration_is_found_without_an_edit(tmp_path):
+    """A token-level configuration and its traffic, added as files in a copy
+    of the benchmark, resolve to the causal-LM task, which makes their job."""
+    import jax
+
+    here = tmp_path / "benchmarks" / "chip"
+    shutil.copytree(HERE, here, ignore=shutil.ignore_patterns("__pycache__", "fixtures"))
+    (here / "configs" / "toy-lm.py").write_text(TOY_LM)
+    sizes = {"name": "toy-lm", "module": "toy-lm.py", "task": "causal-lm",
+             "vocab_size": 32, "hidden_size": 8}
+    (here / "configs" / "toy-lm.json").write_text(json.dumps(sizes))
+    traffic = workload.load_traffic("sync-fp32")
+    traffic["fft"].update(n_clients=4)
+    traffic["data"] = {"partition": "group_domains", "seq_len": 8, "private_sequences": 16,
+                       "public_sequences": 4, "test_sequences": 4, "group_size": 2,
+                       "buckets": 4, "hop_prob": 0.8}
+    (here / "traffic" / "tokens.json").write_text(json.dumps(traffic))
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bench["configs"].append(dict(bench["configs"][0], name="toy-lm",
+                                 file="benchmarks/chip/configs/toy-lm.json"))
+    bench["workloads"].append(dict(bench["workloads"][0], name="toy-lm.tokens",
+                                   config="toy-lm", traffic="tokens"))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    got = run.load_benchmark(tmp_path)
+    cell, entry = run.find_cell(got, "toy-lm.tokens")
+    sizes, mod = run.load_config(entry, tmp_path)
+    task = run.load_task(sizes["task"], here)
+    assert Path(task.__file__).name == "causal_lm.py"
+    traffic = workload.load_traffic(cell["traffic"], here)
+    job = task.make_job(traffic, sizes, workload.seed_key(2 ** 31 + 3))
+    assert job.private.x.shape == (16, 8) and job.n_classes == 4
+    assert task.flops_per_sample(mod, sizes, traffic) == mod.train_flops(sizes, 8)
+    base, w = mod.init(sizes, jax.random.PRNGKey(0))
+    logits = mod.reference_logits(sizes, base, w, job.test.x)
+    assert float(task.loss(logits, job.test.y)) > 0
+
+
+def test_an_unknown_task_lists_the_known_ones():
+    with pytest.raises(ValueError) as err:
+        run.load_task("image-regression")
+    assert "'causal-lm'" in str(err.value) and "'image-classes'" in str(err.value)
+    with pytest.raises(ValueError):
+        run.load_task("../run")
 
 
 def test_peaks_are_keyed_by_device_kind():
