@@ -31,13 +31,20 @@ class ModelConfig:
     head_dim: Optional[int] = None   # default d_model // num_heads
     # --- attention variants ---
     rope_theta: float = 10000.0
+    # YaRN (DeepSeek-V2): factor 1 is plain RoPE
+    rope_yarn_factor: float = 1.0
+    rope_yarn_original_max: int = 4096
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_mscale: float = 1.0
+    rope_yarn_mscale_all_dim: float = 0.0
     qk_norm: bool = False
     attn_bias: bool = False
     sliding_window: Optional[int] = None   # None = full attention
     # MLA (deepseek-v2)
     mla: bool = False
     mla_kv_lora_rank: int = 512
-    mla_q_lora_rank: int = 1536
+    mla_q_lora_rank: int = 1536      # 0: a plain query projection
     mla_rope_head_dim: int = 64
     mla_nope_head_dim: int = 128
     mla_v_head_dim: int = 128
@@ -52,6 +59,10 @@ class ModelConfig:
     first_k_dense: int = 0           # deepseek: first k layers use dense FFN
     router_aux_loss_coef: float = 0.001
     moe_capacity_factor: float = 2.0  # expert-parallel slack (§Perf B3)
+    moe_norm_topk_prob: bool = True  # renormalise the top-k gates to sum 1
+    moe_routed_scaling: float = 1.0  # routed gates' factor
+    moe_experts_held: int = 0        # routed experts this layer holds (0: all)
+    moe_expert_offset: int = 0       # id of the first held expert
     # --- SSM / xLSTM / hybrid ---
     block_pattern: Optional[Tuple[str, ...]] = None  # per-layer kinds; None -> all ATTN
     ssm_state_size: int = 64
@@ -93,7 +104,10 @@ class ModelConfig:
             if kind in (ATTN, SHARED_ATTN):
                 if self.mla:
                     qh = self.mla_nope_head_dim + self.mla_rope_head_dim
-                    total += d * self.mla_q_lora_rank + self.mla_q_lora_rank * nq * qh
+                    if self.mla_q_lora_rank:
+                        total += d * self.mla_q_lora_rank + self.mla_q_lora_rank * nq * qh
+                    else:
+                        total += d * nq * qh
                     total += d * (self.mla_kv_lora_rank + self.mla_rope_head_dim)
                     total += self.mla_kv_lora_rank * nq * (self.mla_nope_head_dim + self.mla_v_head_dim)
                     total += nq * self.mla_v_head_dim * d
@@ -117,7 +131,7 @@ class ModelConfig:
         if self.moe:
             eff = self.moe_d_ff or self.d_ff
             n_mats = 3 if self.ffn_activation in ("swiglu", "geglu") else 2
-            routed = self.num_experts * n_mats * d * eff
+            routed = (self.moe_experts_held or self.num_experts) * n_mats * d * eff
             shared = self.num_shared_experts * n_mats * d * eff
             return routed + shared + d * self.num_experts
         n_mats = 3 if self.ffn_activation in ("swiglu", "geglu") else 2
@@ -169,6 +183,7 @@ def _ensure_loaded():
         "deepseek_v2_236b", "llava_next_mistral_7b", "starcoder2_7b",
         "mixtral_8x22b", "xlstm_125m", "qwen3_1p7b", "codeqwen15_7b",
         "zamba2_1p2b", "gemma_7b", "seamless_m4t_large_v2", "paper_vit",
+        "deepseek_v2_lite",
     ):
         importlib.import_module(f"repro.configs.{mod}")
     _LOADED = True

@@ -39,8 +39,14 @@ def batches_from_stream(stream: np.ndarray, batch: int, seq: int,
         yield toks.astype(np.int32), labels.astype(np.int32)
 
 
+def token_buckets(tokens: np.ndarray, n_buckets: int) -> np.ndarray:
+    """Each token's hash bucket, (t * 2654435761 mod 2^31) mod B, in the
+    tokens' shape."""
+    t = np.asarray(tokens).astype(np.int64)
+    return (t * 2654435761 % (2 ** 31)) % n_buckets
+
+
 def token_class_histogram(tokens: np.ndarray, n_buckets: int) -> np.ndarray:
     """Hashed token histogram — the LM generalization of label histograms."""
-    t = tokens.reshape(-1).astype(np.int64)
-    return np.bincount((t * 2654435761 % (2 ** 31)) % n_buckets,
+    return np.bincount(token_buckets(tokens, n_buckets).reshape(-1),
                        minlength=n_buckets).astype(np.int64)

@@ -14,13 +14,18 @@ in the deadline simulator.
 
 Local updates are one jitted ``lax.scan`` of E minibatch-SGD steps; client
 datasets are resampled to a common static shape so a single compiled update
-serves every participant.
+serves every participant. In LoRA mode the frozen base is an argument of the
+jitted programs, never a constant compiled into them.
+
+Targets are class labels (N,) or next-token targets (N, S). Token targets
+take the mean next-token cross-entropy over all N x S positions, and the
+FedAuto rows count hashed token buckets (``repro.data.tokens``) in place of
+classes.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import functools
 from typing import Any, Callable, List, Optional, Sequence
 
 import jax
@@ -29,6 +34,7 @@ import numpy as np
 
 from repro.core.strategies import Strategy
 from repro.data.synthetic import Dataset
+from repro.data.tokens import token_buckets, token_class_histogram
 from repro.fl import failures as fail_mod
 from repro.fl import network as net_mod
 from repro.fl.lora import LoRAConfig, apply_lora, lora_init
@@ -174,10 +180,10 @@ class FFTRunner:
             self.client_x.append(jnp.asarray(private.x[res]))
             self.client_y.append(jnp.asarray(private.y[res]))
         self.client_hists = np.stack([
-            class_histogram(private.y[np.asarray(ix)], self.n_classes)
+            self._histogram(private.y[np.asarray(ix)])
             if len(ix) else np.zeros(self.n_classes, dtype=np.int64)
             for ix in client_indices])
-        self.server_hist = class_histogram(public.y, self.n_classes)
+        self.server_hist = self._histogram(public.y)
         self.global_hist = self.server_hist + self.client_hists.sum(axis=0)
 
         pub_res = self.rng.choice(len(public.y), self.data_size, replace=True)
@@ -331,29 +337,41 @@ class FFTRunner:
     def trainable(self, params):
         return params
 
-    def _effective(self, t):
+    def _histogram(self, y):
+        """FedAuto's row of targets: class counts, or for token targets
+        (N, S) the counts of their hash buckets."""
+        if np.ndim(y) > 1:
+            return token_class_histogram(np.asarray(y), self.n_classes)
+        return class_histogram(y, self.n_classes)
+
+    def _base(self):
+        """The frozen base the jitted programs take (LoRA), else None."""
+        return self.base_params if self.lora_cfg is not None else None
+
+    def _effective(self, base, t):
         if self.lora_cfg is not None:
-            return apply_lora(self.base_params, t, self.lora_cfg)
+            return apply_lora(base, t, self.lora_cfg)
         return t
 
     def _build_jits(self):
         apply_fn = self.apply_fn
         E, bs = self.cfg.local_steps, self.cfg.batch_size
 
-        def loss_t(t, x, y):
-            logits = apply_fn(self._effective(t), x)
+        def loss(t, base, x, y):
+            logits = apply_fn(self._effective(base, t), x)
             logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-            return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=1))
+            if y.ndim == 1:
+                return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=1))
+            # next-token targets (N, S): the mean over all N x S positions
+            return -jnp.mean(jnp.take_along_axis(logp, y[..., None], axis=-1))
 
-        self._loss_t = loss_t
-
-        @functools.partial(jax.jit, static_argnames=())
-        def local_update(t, t_global, corr, x, y, key, lr, mu):
+        @jax.jit
+        def local_update(base, t, t_global, corr, x, y, key, lr, mu):
             n = x.shape[0]
 
             def step(tt, k):
                 idx = jax.random.randint(k, (bs,), 0, n)
-                g = jax.grad(loss_t)(tt, x[idx], y[idx])
+                g = jax.grad(loss)(tt, base, x[idx], y[idx])
                 g = jax.tree.map(
                     lambda gg, p_, pg: gg.astype(jnp.float32) +
                     mu * (p_.astype(jnp.float32) - pg.astype(jnp.float32)),
@@ -370,18 +388,22 @@ class FFTRunner:
             t, _ = jax.lax.scan(step, t, keys)
             return t
 
-        self._local_update = local_update
+        def local_update_at_base(t, t_global, corr, x, y, key, lr, mu):
+            return local_update(self._base(), t, t_global, corr, x, y, key, lr, mu)
+
+        self._update_program = local_update          # jit(base, t, ...)
+        self._local_update = local_update_at_base
 
         @jax.jit
-        def accuracy_batch(t, x, y):
-            logits = apply_fn(self._effective(t), x)
+        def accuracy_batch(base, t, x, y):
+            logits = apply_fn(self._effective(base, t), x)
             return jnp.sum(jnp.argmax(logits, -1) == y)
 
         self._accuracy_batch = accuracy_batch
 
         @jax.jit
-        def loss_on(t, x, y):
-            return loss_t(t, x, y)
+        def loss_on(base, t, x, y):
+            return loss(t, base, x, y)
 
         self._loss_on = loss_on
 
@@ -410,7 +432,7 @@ class FFTRunner:
         return out
 
     def loss_on(self, t, x, y):
-        return self._loss_on(t, x, y)
+        return self._loss_on(self._base(), t, x, y)
 
     def public_proxy_batch(self, n: int, rnd: int):
         idx = self.rng.integers(0, len(self.public_y_raw), n)
@@ -426,7 +448,13 @@ class FFTRunner:
         """Module 1 (Eq. 6): E SGD steps on the missing-class public subset."""
         with self.telemetry.timer("phase.compensatory", round=rnd):
             miss_classes = np.where(miss_mask)[0]
-            sel = np.isin(np.asarray(self.public_y_raw), miss_classes)
+            public_y = np.asarray(self.public_y_raw)
+            if public_y.ndim > 1:
+                # token rows: those holding a target in a missing bucket
+                buckets = token_buckets(public_y, self.n_classes)
+                sel = np.isin(buckets, miss_classes).any(axis=1)
+            else:
+                sel = np.isin(public_y, miss_classes)
             idx = np.where(sel)[0]
             if len(idx) == 0:
                 return None, None
@@ -434,8 +462,7 @@ class FFTRunner:
             x = self.public_x_raw[res]
             y = self.public_y_raw[res]
             model = self.run_local(self.global_params, x, y, rnd)
-            hist = class_histogram(np.asarray(self.public_y_raw)[idx],
-                                   self.n_classes)
+            hist = self._histogram(public_y[idx])
         return model, hist
 
     def pretrain(self, steps: int) -> None:
@@ -447,7 +474,7 @@ class FFTRunner:
 
     def evaluate(self) -> float:
         with self.telemetry.timer("phase.eval"):
-            t = self.global_params
+            t, base = self.global_params, self._base()
             bs = self.cfg.eval_batch
             n = len(self.test.y)
             correct = 0
@@ -455,8 +482,9 @@ class FFTRunner:
                 x = jnp.asarray(self.test.x[i:i + bs])
                 y = jnp.asarray(self.test.y[i:i + bs])
                 # int() already forces the device sum, so the timer is honest
-                correct += int(self._accuracy_batch(t, x, y))
-            return correct / n
+                correct += int(self._accuracy_batch(base, t, x, y))
+            # token targets: the share of next tokens predicted
+            return correct / np.size(self.test.y)
 
     def _draw_network(self, r: int):
         """(up, met_deadline, RoundEvents|None) for round ``r``.

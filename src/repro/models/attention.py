@@ -18,7 +18,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import dist
-from repro.models.layers import apply_rope, constrain, dense, dense_init, rmsnorm, rmsnorm_init
+from repro.models.layers import (apply_rope, constrain, dense, dense_init, rmsnorm, rmsnorm_init,
+                                 rope_frequencies, yarn_frequencies, yarn_mscale)
 
 NEG_INF = -1e30
 
@@ -38,10 +39,14 @@ def attn_init(key, cfg: ModelConfig, dtype):
     ks = jax.random.split(key, 8)
     if cfg.mla:
         qh = cfg.mla_nope_head_dim + cfg.mla_rope_head_dim
+        if cfg.mla_q_lora_rank:
+            q = {"q_down": dense_init(ks[0], cfg.d_model, cfg.mla_q_lora_rank, dtype),
+                 "q_norm": rmsnorm_init(cfg.mla_q_lora_rank, dtype),
+                 "q_up": dense_init(ks[1], cfg.mla_q_lora_rank, cfg.num_heads * qh, dtype)}
+        else:
+            q = {"wq": dense_init(ks[0], cfg.d_model, cfg.num_heads * qh, dtype)}
         return {
-            "q_down": dense_init(ks[0], cfg.d_model, cfg.mla_q_lora_rank, dtype),
-            "q_norm": rmsnorm_init(cfg.mla_q_lora_rank, dtype),
-            "q_up": dense_init(ks[1], cfg.mla_q_lora_rank, cfg.num_heads * qh, dtype),
+            **q,
             "kv_down": dense_init(
                 ks[2], cfg.d_model, cfg.mla_kv_lora_rank + cfg.mla_rope_head_dim, dtype),
             "kv_norm": rmsnorm_init(cfg.mla_kv_lora_rank, dtype),
@@ -309,31 +314,60 @@ def gqa_decode(p, cfg: ModelConfig, x, cache: KVCache):
 # MLA (DeepSeek-V2)
 # ---------------------------------------------------------------------------
 def _mla_project_q(p, cfg, x, B, S):
-    q = dense(p["q_down"], x)
-    q = rmsnorm(p["q_norm"], q, cfg.norm_eps)
+    if "wq" in p:                                   # no query compression
+        q = dense(p["wq"], x)
+    else:
+        q = dense(p["q_down"], x)
+        q = rmsnorm(p["q_norm"], q, cfg.norm_eps)
+        q = dense(p["q_up"], q)
     qh = cfg.mla_nope_head_dim + cfg.mla_rope_head_dim
-    q = dense(p["q_up"], q).reshape(B, S, cfg.num_heads, qh)
+    q = q.reshape(B, S, cfg.num_heads, qh)
     return jnp.split(q, [cfg.mla_nope_head_dim], axis=-1)   # nope, rope
+
+
+def mla_rope(cfg: ModelConfig):
+    """(inverse frequencies, cos/sin factor) of MLA's rope dims: theta's
+    own, or YaRN's with mscale over mscale_all_dim."""
+    rd = cfg.mla_rope_head_dim
+    f = cfg.rope_yarn_factor
+    if f <= 1:
+        return rope_frequencies(rd, cfg.rope_theta), 1.0
+    freqs = yarn_frequencies(rd, cfg.rope_theta, f, cfg.rope_yarn_original_max,
+                             cfg.rope_yarn_beta_fast, cfg.rope_yarn_beta_slow)
+    return freqs, (yarn_mscale(f, cfg.rope_yarn_mscale)
+                   / yarn_mscale(f, cfg.rope_yarn_mscale_all_dim))
+
+
+def _yarn_temperature(cfg: ModelConfig) -> float:
+    """YaRN's mscale_all_dim factor squared, which multiplies the softmax scale."""
+    if cfg.rope_yarn_factor > 1 and cfg.rope_yarn_mscale_all_dim:
+        return yarn_mscale(cfg.rope_yarn_factor, cfg.rope_yarn_mscale_all_dim) ** 2
+    return 1.0
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(nope + rope)^-1/2, times YaRN's temperature."""
+    return _yarn_temperature(cfg) / math.sqrt(cfg.mla_nope_head_dim + cfg.mla_rope_head_dim)
 
 
 def mla_forward(p, cfg: ModelConfig, x, positions, q_chunk: int = 2048):
     """Training/prefill MLA: expand the latent, run standard attention."""
     B, S, _ = x.shape
     nh, nd, rd, vd = cfg.num_heads, cfg.mla_nope_head_dim, cfg.mla_rope_head_dim, cfg.mla_v_head_dim
+    freqs, rs = mla_rope(cfg)
     q_nope, q_rope = _mla_project_q(p, cfg, x, B, S)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, freqs, rs)
     kv = dense(p["kv_down"], x)
     c_kv, k_rope = jnp.split(kv, [cfg.mla_kv_lora_rank], axis=-1)
     c_kv = rmsnorm(p["kv_norm"], c_kv, cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)  # (B,S,1,rd)
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta, freqs, rs)  # (B,S,1,rd)
     kvu = dense(p["kv_up"], c_kv).reshape(B, S, nh, nd + vd)
     k_nope, v = jnp.split(kvu, [nd], axis=-1)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (B, S, nh, rd))], axis=-1)
     q = constrain(q, "batch", "seq", "heads", None)
-    scale = 1.0 / math.sqrt(nd + rd)
     o = _sdpa(q, k, v, causal=True, window=cfg.sliding_window, q_offset=0,
-              scale=scale, q_chunk=q_chunk)
+              scale=mla_softmax_scale(cfg), q_chunk=q_chunk)
     return dense(p["wo"], o.reshape(B, S, nh * vd))
 
 
@@ -350,13 +384,14 @@ def mla_decode(p, cfg: ModelConfig, x, cache: KVCache):
     nh, nd, rd, vd = cfg.num_heads, cfg.mla_nope_head_dim, cfg.mla_rope_head_dim, cfg.mla_v_head_dim
     lora = cfg.mla_kv_lora_rank
     pos = cache.length
+    freqs, rs = mla_rope(cfg)
     q_nope, q_rope = _mla_project_q(p, cfg, x, B, 1)
     posb = jnp.full((B, 1), pos, jnp.int32)
-    q_rope = apply_rope(q_rope, posb, cfg.rope_theta)       # (B,1,H,rd)
+    q_rope = apply_rope(q_rope, posb, cfg.rope_theta, freqs, rs)       # (B,1,H,rd)
     kv = dense(p["kv_down"], x)                             # (B,1,lora+rd)
     c_kv, k_rope = jnp.split(kv, [lora], axis=-1)
     c_kv = rmsnorm(p["kv_norm"], c_kv, cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], posb, cfg.rope_theta)[:, :, 0, :]
+    k_rope = apply_rope(k_rope[:, :, None, :], posb, cfg.rope_theta, freqs, rs)[:, :, 0, :]
     ck = jax.lax.dynamic_update_slice(cache.k, c_kv.astype(cache.k.dtype), (0, pos, 0))
     cr = jax.lax.dynamic_update_slice(cache.v, k_rope.astype(cache.v.dtype), (0, pos, 0))
     # absorb kv_up into the query:  q_c[h] = W_uk[h]^T q_nope[h]
@@ -368,6 +403,9 @@ def mla_decode(p, cfg: ModelConfig, x, cache: KVCache):
     s = s + jnp.einsum("bhr,bsr->bhs", q_rope[:, 0].astype(jnp.float32),
                        cr.astype(jnp.float32))
     s = s / math.sqrt(nd + rd)
+    temperature = _yarn_temperature(cfg)
+    if temperature != 1.0:
+        s = s * temperature
     kpos = jnp.arange(cache.k.shape[1])
     s = jnp.where((kpos <= pos)[None, None, :], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)

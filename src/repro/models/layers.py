@@ -103,13 +103,44 @@ def rope_frequencies(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, head_dim); positions: broadcastable to (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor, 0.1 m ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(head_dim: int, theta: float, factor: float, original_max: int,
+                     beta_fast: float, beta_slow: float) -> jax.Array:
+    """YaRN inverse frequencies, as DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``
+    computes them: dims that turn more than ``beta_fast`` times over
+    ``original_max`` positions keep theta's frequency, dims that turn fewer
+    than ``beta_slow`` times are divided by ``factor``, with a linear ramp
+    between."""
+    def dim_of(turns):
+        return head_dim * math.log(original_max / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    f_e = rope_frequencies(head_dim, theta)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    keep = 1.0 - ramp
+    return f_e * keep + f_e / factor * (1.0 - keep)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs: Optional[jax.Array] = None, scale: float = 1.0) -> jax.Array:
+    """x: (..., S, H, head_dim); positions: broadcastable to (..., S).
+    ``freqs`` replaces theta's inverse frequencies (YaRN), and ``scale``
+    multiplies cos and sin."""
     head_dim = x.shape[-1]
-    freqs = rope_frequencies(head_dim, theta)                 # (half,)
+    if freqs is None:
+        freqs = rope_frequencies(head_dim, theta)             # (half,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, half)
     angles = angles[..., None, :]                              # (..., S, 1, half)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -8,10 +8,19 @@ local experts with a sort, runs grouped matmuls via ``jax.lax.ragged_dot``
 ``psum``s over the tensor axis. No all-to-all is needed because activations
 are already replicated across that axis — the psum doubles as the combine.
 
-Two paths:
+Three paths:
   * ``_moe_local``  — single device / GSPMD-auto fallback (also the oracle).
   * ``_moe_sharded`` — shard_map expert-parallel path, enabled when a
     MeshContext is installed and num_experts % model_axis_size == 0.
+  * ``_moe_held`` — one chip's share of an expert-parallel deployment
+    (``cfg.moe_experts_held`` < ``num_experts``): the layer holds experts
+    ``moe_expert_offset`` .. + held, routes over all ``num_experts`` and
+    computes the part of the result its own experts give, every (token,
+    expert) pair of them, with no exchange.
+
+Named scopes: ``moe.route`` (router, top-k, sort, group sizes),
+``moe.experts`` (gather, grouped products, gate-weighted scatter-add) and
+``moe.shared``.
 """
 from __future__ import annotations
 
@@ -28,12 +37,13 @@ from repro.models.layers import dense_init, gelu
 
 
 def moe_init(key, cfg: ModelConfig, dtype):
+    """Router over all ``num_experts``; expert stacks of the held ones."""
     d_ff = cfg.moe_d_ff or cfg.d_ff
-    E = cfg.num_experts
+    E = cfg.moe_experts_held or cfg.num_experts
     ks = jax.random.split(key, 6)
     scale = 1.0 / jnp.sqrt(cfg.d_model).astype(jnp.float32)
     p = {
-        "router": dense_init(ks[0], cfg.d_model, E, jnp.float32),
+        "router": dense_init(ks[0], cfg.d_model, cfg.num_experts, jnp.float32),
         "w_gate": (jax.random.normal(ks[1], (E, cfg.d_model, d_ff)) * scale).astype(dtype),
         "w_up": (jax.random.normal(ks[2], (E, cfg.d_model, d_ff)) * scale).astype(dtype),
         "w_down": (jax.random.normal(ks[3], (E, d_ff, cfg.d_model))
@@ -52,10 +62,15 @@ def _activation(cfg, g, u):
 
 def _route(p, cfg: ModelConfig, x2d):
     """x2d: (T, d) -> (gates (T,k), eids (T,k) int32, aux_loss scalar)."""
-    logits = (x2d.astype(jnp.float32) @ p["router"]["w"])          # (T, E)
+    logits = jnp.dot(x2d.astype(jnp.float32), p["router"]["w"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)          # (T, E)
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, eids = jax.lax.top_k(probs, cfg.num_experts_per_tok)
-    gates = top_p / jnp.sum(top_p, axis=-1, keepdims=True)         # renormalize
+    gates = top_p
+    if cfg.moe_norm_topk_prob:
+        gates = top_p / jnp.sum(top_p, axis=-1, keepdims=True)     # renormalize
+    if cfg.moe_routed_scaling != 1.0:
+        gates = gates * cfg.moe_routed_scaling
     # Switch-style load-balance loss: E * sum_e f_e * p_e
     E = cfg.num_experts
     me = jnp.mean(probs, axis=0)                                   # (E,)
@@ -83,17 +98,54 @@ def _sort_by_expert(eids_flat, num_buckets):
 def _moe_local(p, cfg: ModelConfig, x2d):
     T, d = x2d.shape
     k, E = cfg.num_experts_per_tok, cfg.num_experts
-    gates, eids, aux = _route(p, cfg, x2d)
-    eflat = eids.reshape(T * k)
-    gflat = gates.reshape(T * k)
-    tok = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
-    se, perm = _sort_by_expert(eflat, E)
-    tok_s, gate_s = tok[perm], gflat[perm]
-    group_sizes = jnp.bincount(se, length=E).astype(jnp.int32)
-    x_sel = x2d[tok_s]                                              # (T*k, d)
-    y_sel = _grouped_ffn(cfg, x_sel, p["w_gate"], p["w_up"], p["w_down"], group_sizes)
-    out = jnp.zeros_like(x2d).at[tok_s].add(
-        (y_sel.astype(jnp.float32) * gate_s[:, None]).astype(x2d.dtype))
+    with jax.named_scope("moe.route"):
+        gates, eids, aux = _route(p, cfg, x2d)
+        eflat = eids.reshape(T * k)
+        gflat = gates.reshape(T * k)
+        tok = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
+        se, perm = _sort_by_expert(eflat, E)
+        tok_s, gate_s = tok[perm], gflat[perm]
+        group_sizes = jnp.bincount(se, length=E).astype(jnp.int32)
+    with jax.named_scope("moe.experts"):
+        x_sel = x2d[tok_s]                                          # (T*k, d)
+        y_sel = _grouped_ffn(cfg, x_sel, p["w_gate"], p["w_up"], p["w_down"], group_sizes)
+        out = jnp.zeros_like(x2d).at[tok_s].add(
+            (y_sel.astype(jnp.float32) * gate_s[:, None]).astype(x2d.dtype))
+    return out, aux
+
+
+def held_rows(cfg: ModelConfig, tokens: int) -> int:
+    """Static row bound of the held-expert layer: top-k picks k distinct
+    experts per token, so a token sends at most min(k, held) pairs here."""
+    return tokens * min(cfg.num_experts_per_tok, cfg.moe_experts_held)
+
+
+def _moe_held(p, cfg: ModelConfig, x2d):
+    """The routed part that the held experts give. p's expert stacks are
+    (held, ...); the router has all ``num_experts`` outputs. Held pairs sort
+    to a prefix grouped by held expert, and the grouped products run over
+    ``held_rows`` rows, so no pair is ever dropped. Rows past the held pairs
+    lie in no group, and what the grouped products leave there is
+    unspecified (the TPU's are not zero): they are masked on the way in and
+    on the way out, so they add nothing to the result or to its gradient."""
+    T, d = x2d.shape
+    k, held, off = cfg.num_experts_per_tok, cfg.moe_experts_held, cfg.moe_expert_offset
+    rows = held_rows(cfg, T)
+    with jax.named_scope("moe.route"):
+        gates, eids, aux = _route(p, cfg, x2d)
+        local = eids.reshape(T * k) - off
+        key = jnp.where((local >= 0) & (local < held), local, held)   # held: sentinel
+        sk, perm = jax.lax.sort_key_val(key, jnp.arange(T * k, dtype=jnp.int32))
+        sk, perm = sk[:rows], perm[:rows]
+        tok_s = perm // k
+        mine = sk < held
+        gate_s = gates.reshape(T * k)[perm]
+        group_sizes = jnp.bincount(sk, length=held + 1)[:held].astype(jnp.int32)
+    with jax.named_scope("moe.experts"):
+        x_sel = jnp.where(mine[:, None], x2d[tok_s], 0.0)            # (rows, d)
+        y_sel = _grouped_ffn(cfg, x_sel, p["w_gate"], p["w_up"], p["w_down"], group_sizes)
+        y_sel = jnp.where(mine[:, None], y_sel.astype(jnp.float32) * gate_s[:, None], 0.0)
+        out = jnp.zeros_like(x2d).at[tok_s].add(y_sel.astype(x2d.dtype))
     return out, aux
 
 
@@ -177,7 +229,10 @@ def moe_forward(p, cfg: ModelConfig, x) -> Tuple[jax.Array, jax.Array]:
     d_ff = cfg.moe_d_ff or cfg.d_ff
     bspec = P(ctx.batch_axes, None, None) if ctx else None
     m = ctx.model_axis if ctx else None
-    if ctx is not None and E % ms == 0 and (B % ctx.batch_size == 0):
+    if 0 < cfg.moe_experts_held < E:
+        out2d, aux = _moe_held(p, cfg, x.reshape(B * S, d))
+        out = out2d.reshape(B, S, d)
+    elif ctx is not None and E % ms == 0 and (B % ctx.batch_size == 0):
         E_loc = E // ms
         T_loc = (B // ctx.batch_size) * S
         # expected local load = T_loc*k*E_loc/E, scaled by the capacity
@@ -223,5 +278,6 @@ def moe_forward(p, cfg: ModelConfig, x) -> Tuple[jax.Array, jax.Array]:
         out = out2d.reshape(B, S, d)
     if cfg.num_shared_experts:
         from repro.models.ffn import ffn_forward
-        out = out + ffn_forward(p["shared"], cfg, x)
+        with jax.named_scope("moe.shared"):
+            out = out + ffn_forward(p["shared"], cfg, x)
     return out, aux * cfg.router_aux_loss_coef

@@ -13,6 +13,7 @@ API:
   init_params(key, cfg)               -> pytree
   forward(params, cfg, batch)         -> (loss, metrics)        # train
   hidden_states(params, cfg, batch)   -> (B,S,d)                # backbone out
+  apply(params, cfg, tokens)          -> (B,S,V) logits         # fine-tuning
   init_decode_state(params, cfg, batch, cache_len) -> state
   decode_step(params, cfg, state, tokens (B,1)) -> (logits (B,V), state)
 """
@@ -73,7 +74,8 @@ def block_forward(p, cfg: ModelConfig, kind: str, x, positions, *,
     h = rmsnorm(p["ln1"], x, cfg.norm_eps)
     if kind in (ATTN, SHARED_ATTN):
         if cfg.mla:
-            a = attn.mla_forward(p["attn"], cfg, h, positions, q_chunk=q_chunk)
+            with jax.named_scope("mla"):
+                a = attn.mla_forward(p["attn"], cfg, h, positions, q_chunk=q_chunk)
         else:
             a = attn.gqa_forward(p["attn"], cfg, h, positions, causal=causal,
                                  q_chunk=q_chunk)
@@ -248,6 +250,14 @@ def forward(params, cfg: ModelConfig, batch: Dict[str, jax.Array],
     loss, cnt = chunked_cross_entropy(h, _lm_head_w(params, cfg), batch["labels"],
                                       chunk=loss_chunk)
     return loss + aux, {"ce_loss": loss, "aux_loss": aux, "target_tokens": cnt}
+
+
+def apply(params, cfg: ModelConfig, tokens, q_chunk: int = 2048):
+    """float32 logits (B, S, V) of tokens (B, S): the backbone, with remat per
+    scanned layer, then the lm head. The router's auxiliary balance loss is
+    not part of it."""
+    h, _ = hidden_states(params, cfg, {"tokens": tokens}, q_chunk=q_chunk)
+    return (h @ _lm_head_w(params, cfg)).astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------

@@ -125,7 +125,7 @@ def _plain_update(r, t, tg, corr, x, y, key, lr, mu):
     f32 = lambda a: a.astype(jnp.float32)
     for k in jax.random.split(key, r.cfg.local_steps):
         idx = jax.random.randint(k, (bs,), 0, n)
-        g = jax.grad(r._loss_t)(t, x[idx], y[idx])
+        g = jax.grad(r.loss_on)(t, x[idx], y[idx])
         g = jax.tree.map(lambda gg, p, pg, c: f32(gg) + mu * (f32(p) - f32(pg)) + c,
                          g, t, tg, corr)
         t = jax.tree.map(lambda p, gg: (f32(p) - lr * gg).astype(p.dtype), t, g)
