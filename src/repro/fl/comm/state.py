@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.fl.comm.codecs import Codec, Payload, make_codec
+from repro.fl.comm.codecs import Codec, EncodedLeaf, Payload, make_codec
 from repro.obs.telemetry import NULL_TELEMETRY
 
 
@@ -188,6 +188,8 @@ class CommState:
         self.wire_nbytes = codec.nbytes(template)
         self.compression_ratio = self.wire_nbytes / max(self.fp32_nbytes, 1)
         self._codec_cache: Dict[str, Codec] = {codec.name: codec}
+        # one jitted delta-and-encode per codec name (``_encode_program``)
+        self._encode_programs: Dict[str, Any] = {}
         self._nbytes_cache: Dict[str, float] = {}
         # Simulated sizes: exact codec bytes by default; scaled by the
         # codec's measured ratio under an explicit model_bytes override.
@@ -256,38 +258,81 @@ class CommState:
     def residual(self, client: int):
         return self._residuals.get(client)
 
+    def _encode_program(self, codec: Codec):
+        """The jitted program of one upload under ``codec``, built once per
+        codec name; jit's own cache specialises it on the tree, the shapes
+        and whether a residual is carried (``resid=None`` is an empty
+        pytree, so that case traces a program with no residual add).
+
+        ``_encode_delta(model, global_params, resid)`` computes the fp32
+        delta plus the residual.  For a lossless codec it also encodes
+        every leaf and returns the leaves' ``data`` dicts: one dispatch per
+        upload where the eager form made one per leaf.  A lossy codec gets
+        the carry back and encodes it eagerly: under jit XLA turns a
+        division by a constant into a product with its reciprocal and
+        fuses multiply-subtract, which moves quantizer scales and residuals
+        off the eager values by an ulp."""
+        fn = self._encode_programs.get(codec.name)
+        if fn is None:
+            def _encode_delta(model, global_params, resid):
+                tel = self.telemetry
+                if tel:
+                    # the body runs only when jit traces a new program
+                    tel.counter("comm.encode_traces")
+                carry = jax.tree.map(
+                    lambda w, g: w.astype(jnp.float32) - g.astype(jnp.float32),
+                    model, global_params)
+                if resid is not None:
+                    carry = jax.tree.map(jnp.add, carry, resid)
+                if not codec.lossless:
+                    return carry
+                return [codec.encode_leaf(l).data
+                        for l in jax.tree.leaves(carry)]
+
+            fn = self._encode_programs[codec.name] = jax.jit(_encode_delta)
+        return fn
+
     def _encode(self, client: int, model, global_params,
                 codec: Optional[Codec]):
         """Client-side half of one upload: delta, EF carry, encode, residual
         update, byte charging.  Returns ``(payload, decoded, distortion)``.
-        The transient ``decoded`` pytree exists because error feedback needs
-        the client to know exactly what the server will reconstruct (and the
-        distortion measurement rides on it); callers that stream drop it
-        immediately, ``roundtrip`` reuses it so the materializing path never
-        decodes twice."""
+
+        The delta (plus residual) and a lossless encode are one jitted
+        program (``_encode_program``).  A lossless codec keeps no residual
+        and builds no decoded tree: ``decoded`` is then None, and
+        ``roundtrip`` decodes the payload itself (for ``fp32`` and
+        ``lora_only`` that is the payload's own arrays, no dispatch).  A
+        lossy codec's ``decoded`` is what the server will reconstruct:
+        error feedback needs it for the residual and the distortion, and
+        ``roundtrip`` reuses it so the materializing path never decodes
+        twice."""
         codec = self.codec if codec is None else codec
-        delta = jax.tree.map(
-            lambda w, g: w.astype(jnp.float32) - g.astype(jnp.float32),
-            model, global_params)
         resid = self._residuals.get(client)
+        out = self._encode_program(codec)(model, global_params, resid)
+        decoded = None
         distortion = 0.0
-        if codec.lossless and resid is None:
-            payload = codec.encode(delta)
-            decoded = codec.decode(payload)
-        else:
-            carry = (delta if resid is None else
-                     jax.tree.map(jnp.add, delta, resid))
-            payload = codec.encode(carry)
-            decoded = codec.decode(payload)
-            if codec.lossless:
+        if codec.lossless:
+            # the layout is value-independent: shapes and byte counts come
+            # from the model, the arrays from the program
+            leaves, treedef = jax.tree.flatten(model)
+            enc = []
+            for leaf, data in zip(leaves, out):
+                shape = tuple(jnp.shape(leaf))
+                enc.append(EncodedLeaf(shape, data, codec.leaf_nbytes(shape)))
+            payload = Payload(codec=codec.name, leaves=enc, treedef=treedef,
+                              nbytes=sum(e.nbytes for e in enc))
+            if resid is not None:
                 # wire carried the full corrected delta: residual flushed
                 self._residuals.pop(client)
-            else:
-                new_resid = jax.tree.map(jnp.subtract, carry, decoded)
-                self._residuals.set(client, new_resid)
-                carry_norm = _l2(carry)
-                if carry_norm > 0.0:
-                    distortion = _l2(new_resid) / carry_norm
+        else:
+            carry = out
+            payload = codec.encode(carry)
+            decoded = codec.decode(payload)
+            new_resid = jax.tree.map(jnp.subtract, carry, decoded)
+            self._residuals.set(client, new_resid)
+            carry_norm = _l2(carry)
+            if carry_norm > 0.0:
+                distortion = _l2(new_resid) / carry_norm
         # accumulate *simulated* wire bytes (override-scaled), the same
         # unit the deadline simulator, traces, and total_downlink_bytes
         # use
@@ -310,11 +355,12 @@ class CommState:
         ``repro.fl.comm.stream.StreamAccumulator`` without ever
         materializing the fp32 delta.  Error-feedback residual mutation,
         distortion bookkeeping, and byte accounting are identical to
-        ``roundtrip`` (they are the same code); only the server-side
-        reconstruction is omitted."""
+        ``roundtrip`` (they are the same code); the server-side
+        reconstruction is omitted, and a lossless upload is one jitted
+        program that builds no decoded tree."""
         tel = self.telemetry
         with tel.timer("phase.uplink", client=client):
-            payload, decoded, distortion = self._encode(
+            payload, _decoded, distortion = self._encode(
                 client, model, global_params, codec)
             if tel:
                 # device time is honest only once the encode finished
@@ -360,14 +406,17 @@ class CommState:
         changes unchanged — EF is codec-agnostic.
 
         This is the composition ``encode_upload`` + reconstruction with the
-        encode-side transient decode reused (one decode total) — the
+        encode-side decode of a lossy codec reused (one decode total) — the
         materializing server path.  Streaming strategies take
         ``encode_upload`` alone and never build ``recon``.
         """
         tel = self.telemetry
         with tel.timer("phase.uplink", client=client):
+            codec = self.codec if codec is None else codec
             payload, decoded, distortion = self._encode(
                 client, model, global_params, codec)
+            if decoded is None:
+                decoded = codec.decode(payload)
             recon = jax.tree.map(
                 lambda g, d: (g.astype(jnp.float32) + d).astype(g.dtype),
                 global_params, decoded)

@@ -224,6 +224,120 @@ def test_lossless_codecs_keep_no_residual():
         assert payload.nbytes == codec.nbytes(tmpl)
 
 
+class _Lora:
+    rank = 2
+
+
+def _mixed_tree(seed, lora=False):
+    """Leaves of mixed shapes and dtypes, a 0-d and a bf16 one among them;
+    ``lora`` arranges them as an adapter dict ({path: {'a', 'b'}})."""
+    rng = np.random.default_rng(seed)
+    leaf = lambda shape, dt=jnp.float32: jnp.asarray(
+        rng.normal(size=shape), dt)
+    if lora:
+        return {"blk0/qkv": {"a": leaf((6, 2)), "b": leaf((2, 6))},
+                "blk1/qkv": {"a": leaf((6, 2), jnp.bfloat16),
+                             "b": leaf((2, 6), jnp.bfloat16)},
+                "head": {"a": leaf(()), "b": leaf((1,))}}
+    return {"conv": leaf((3, 3, 2, 4)), "norm": leaf((9,), jnp.bfloat16),
+            "scale": leaf(()), "fc": leaf((17, 5))}
+
+
+@pytest.mark.parametrize("carried", [False, True],
+                         ids=["no_residual", "carried_residual"])
+@pytest.mark.parametrize("spec", ["fp32", "fp16", "int8", "qsgd:4", "sign1",
+                                  "topk:0.1", "lora_only"])
+def test_encode_program_matches_eager_formula(spec, carried):
+    """The jitted delta-and-encode gives the payload, residual and
+    reconstruction of the eager per-leaf formula
+    ``codec.encode_leaf(w.astype(f32) - g.astype(f32) [+ residual])``
+    bit for bit; the distortion to rounding."""
+    codec = make_codec(spec)
+    lora = spec == "lora_only"
+    g = _mixed_tree(0, lora)
+    model = _mixed_tree(1, lora)
+    states = [CommState(codec, g, lora_cfg=_Lora() if lora else None)
+              for _ in range(2)]
+    if carried:
+        # a lossy upload first leaves a residual behind; a lossless codec
+        # then flushes it, a lossy one carries it on
+        for st in states:
+            st.roundtrip(0, _mixed_tree(2, lora), g, codec=make_codec("int8"))
+    resid = states[0].residual(0)
+    assert (resid is not None) == carried
+
+    leaves, treedef = jax.tree.flatten(model)
+    carry = [w.astype(jnp.float32) - gg.astype(jnp.float32)
+             for w, gg in zip(leaves, jax.tree.leaves(g))]
+    if carried:
+        carry = [c + r for c, r in zip(carry, jax.tree.leaves(resid))]
+    want = [codec.encode_leaf(c) for c in carry]
+    decoded = [codec.decode_leaf(e) for e in want]
+
+    payload, dist = states[0].encode_upload(0, model, g)
+    recon, payload_rt, dist_rt = states[1].roundtrip(0, model, g)
+    for p in (payload, payload_rt):
+        assert p.codec == codec.name and p.treedef == treedef
+        assert p.nbytes == sum(e.nbytes for e in want) == codec.nbytes(g)
+        for got, exp in zip(p.leaves, want, strict=True):
+            assert got.shape == exp.shape and got.nbytes == exp.nbytes
+            assert set(got.data) == set(exp.data)
+            for k in exp.data:
+                np.testing.assert_array_equal(np.asarray(got.data[k]),
+                                              np.asarray(exp.data[k]))
+    want_recon = [(gg.astype(jnp.float32) + d).astype(gg.dtype)
+                  for gg, d in zip(jax.tree.leaves(g), decoded)]
+    for got, exp in zip(jax.tree.leaves(recon), want_recon, strict=True):
+        assert got.dtype == exp.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(exp))
+
+    if codec.lossless:
+        want_resid, want_dist = None, 0.0
+    else:
+        want_resid = [c - d for c, d in zip(carry, decoded)]
+        want_dist = _l2(want_resid) / _l2(carry)
+    for st, d in ((states[0], dist), (states[1], dist_rt)):
+        got_resid = st.residual(0)
+        if want_resid is None:
+            assert got_resid is None and d == 0.0
+        else:
+            for got, exp in zip(jax.tree.leaves(got_resid), want_resid,
+                                strict=True):
+                np.testing.assert_array_equal(np.asarray(got),
+                                              np.asarray(exp))
+            assert d == pytest.approx(want_dist)
+        assert st.last_distortions[0] == d
+
+
+def test_encode_program_traces_once_per_codec():
+    """20 uploads from 5 clients under one codec trace the encode program
+    once; a second rung traces one more, and its residual-carrying variant
+    (a lossy rung's second upload per client) one after that."""
+    from repro.obs.telemetry import Telemetry
+    g = _mixed_tree(0)
+    st = CommState(make_codec("fp32"), g)
+    st.telemetry = tel = Telemetry()
+    traces = lambda: tel.counters.get("comm.encode_traces", 0)
+    for r in range(4):
+        for client in range(5):
+            st.encode_upload(client, _mixed_tree(10 * r + client), g)
+    assert tel.counters["comm.uploads"] == 20
+    assert traces() == 1
+    fp16 = st.codec_named("fp16")
+    for client in range(5):                        # no residual yet
+        st.encode_upload(client, _mixed_tree(100 + client), g, codec=fp16)
+    assert traces() == 2
+    for r in range(2):                             # residuals carried
+        for client in range(5):
+            st.encode_upload(client, _mixed_tree(200 + client), g,
+                             codec=fp16)
+    assert traces() == 3
+    for client in range(5):                        # fp32 flushes them
+        st.encode_upload(client, _mixed_tree(300 + client), g)
+    assert traces() == 4
+    assert len(st._residuals) == 0
+
+
 # ---------------------------------------------------------------------------
 # bytes-on-wire through the deadline simulator
 # ---------------------------------------------------------------------------
